@@ -14,8 +14,10 @@ diagonal are skipped (the kernel's KV loop bound depends on the Q block
 index), so the causal kernel does ~half the FLOPs of a dense one — the same
 work-skipping idea Caiti applies to I/O (never touch what you can avoid).
 
-Validated in interpret mode against kernels/ref.py (CPU container); on a
-real TPU the same pallas_call lowers to Mosaic.
+On the CPU the kernel runs in Pallas interpret mode (the tests check it
+against kernels/ref.py there); on a TPU the same pallas_call lowers to
+Mosaic, and tests/test_tpu_compile.py compiles it for a v5e.  It is off
+the serving path: prefill uses ``ref.flash_attention_ref``.
 """
 from __future__ import annotations
 
@@ -46,10 +48,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
     def body(ki, carry):
         m_prev, l_prev, acc = carry
-        k = pl.load(k_ref, (pl.dslice(ki * bk, bk), slice(None))
-                    ).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(ki * bk, bk), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(pl.multiple_of(ki * bk, bk), bk), :
+                  ].astype(jnp.float32)
+        v = v_ref[pl.ds(pl.multiple_of(ki * bk, bk), bk), :
+                  ].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)

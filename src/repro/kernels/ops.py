@@ -1,34 +1,33 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On the CPU container every op runs the *same kernel body* in interpret mode
-(validating logic + tiling); on TPU (platform == 'tpu') the pallas_call
-lowers to Mosaic.  Model code selects the implementation with the config
-flag ``attn_impl`` — the dry-run uses the XLA path (Pallas TPU kernels do
-not lower on the host platform), which is recorded in DESIGN.md.
+On a TPU (``jax.default_backend() == 'tpu'``) every pallas_call lowers to
+Mosaic and runs as a ``tpu_custom_call``; anywhere else the *same kernel
+body* runs in Pallas interpret mode, which is how the CPU tests validate
+logic and tiling.  ``interpret`` is chosen here by platform only, so on the
+chip it is never True.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from .block_transit import (gather_quantize_crc_pallas,
-                            gather_quantize_pallas,
-                            scatter_dequantize_crc_pallas,
-                            scatter_dequantize_pallas)
+                            scatter_dequantize_crc_pallas)
 from .flash_attention import flash_attention_pallas
 from .paged_attention import paged_attention_pallas
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU: kernels lower to Mosaic
+    there, and run in interpret mode everywhere else."""
     return jax.default_backend() == "tpu"
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_attention(q, k, v, causal, window, bq, bk):
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  bq=bq, bk=bk, interpret=not _on_tpu())
+                                  bq=bq, bk=bk, interpret=not on_tpu())
 
 
 def _flash_fwd(q, k, v, causal, window, bq, bk):
@@ -37,8 +36,8 @@ def _flash_fwd(q, k, v, causal, window, bq, bk):
 
 def _flash_bwd(causal, window, bq, bk, res, g):
     # backward through the jnp oracle (XLA recompute — the standard
-    # fwd-kernel/bwd-recompute split; a dedicated bwd kernel is a TPU-side
-    # optimization outside this container's scope)
+    # fwd-kernel/bwd-recompute split; no training cell needs a dedicated
+    # bwd kernel yet)
     from . import ref
     q, k, v = res
     _, vjp = jax.vjp(
@@ -59,18 +58,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 @jax.jit
 def paged_attention(q, k_pool, v_pool, block_table, seq_lens):
     return paged_attention_pallas(q, k_pool, v_pool, block_table, seq_lens,
-                                  interpret=not _on_tpu())
-
-
-@jax.jit
-def gather_quantize(pool, page_ids):
-    return gather_quantize_pallas(pool, page_ids, interpret=not _on_tpu())
-
-
-@jax.jit
-def scatter_dequantize(pool, page_ids, q, scales):
-    return scatter_dequantize_pallas(pool, page_ids, q, scales,
-                                     interpret=not _on_tpu())
+                                  interpret=not on_tpu())
 
 
 @jax.jit
@@ -79,7 +67,7 @@ def gather_quantize_crc(pool, page_ids):
     payload, the f32 scales, AND the Adler-32 wire checksum (vs the
     three-pass quantize / host-checksum / copy composition)."""
     return gather_quantize_crc_pallas(pool, page_ids,
-                                      interpret=not _on_tpu())
+                                      interpret=not on_tpu())
 
 
 @jax.jit
@@ -87,4 +75,17 @@ def scatter_dequantize_crc(pool, page_ids, q, scales):
     """Fused restore codec: dequantize+scatter plus the checksum of the
     payload as received, for the caller to verify against spill time."""
     return scatter_dequantize_crc_pallas(pool, page_ids, q, scales,
-                                         interpret=not _on_tpu())
+                                         interpret=not on_tpu())
+
+
+@jax.jit
+def gather_quantize(pool, page_ids):
+    """The spill codec without its checksum (same kernel)."""
+    q, scales, _ = gather_quantize_crc(pool, page_ids)
+    return q, scales
+
+
+@jax.jit
+def scatter_dequantize(pool, page_ids, q, scales):
+    """The restore codec without its checksum (same kernel)."""
+    return scatter_dequantize_crc(pool, page_ids, q, scales)[0]

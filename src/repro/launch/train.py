@@ -19,6 +19,7 @@ import jax
 from repro.ckpt import CheckpointEngine, make_blockstore
 from repro.configs import ARCHS, get_config
 from repro.data import SyntheticLM
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import AdamW
 from repro.train.loop import TrainConfig, Trainer
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--accum", type=int, default=1)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     opt = AdamW(lr=args.lr, total_steps=args.steps)

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.compat import shard_map
 
 
 # --------------------------------------------------------------------- norms
@@ -176,7 +175,7 @@ def sharded_attention(q, k, v, *, q_pos, k_pos, causal: bool,
                                  causal=causal, window=window, kv_mask=m_l,
                                  chunk=c, dtype=dtype)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=ctx.mesh,
         in_specs=(P(b, axis, None, None), P(b, axis),
                   P(b, None, None, None), P(b, None, None, None),
@@ -292,7 +291,7 @@ def decode_update_and_attend(q, cache_k, cache_v, cache_pos, new_k, new_v,
         out = acc_all / jnp.maximum(l_all, 1e-30)[..., None]
         return out.reshape(Bl, T, H, hd).astype(dtype), k_l, v_l, cp_l
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=ctx.mesh,
         in_specs=(P(bspec, None, None, None), P(bspec, axis, None, None),
                   P(bspec, axis, None, None), P(bspec, axis),
@@ -351,7 +350,7 @@ def decode_attention(q, k, v, *, k_pos, pos, window: int, kv_mask, ctx,
         out = acc_all / jnp.maximum(l_all, 1e-30)[..., None]
         return out.reshape(Bl, T, H, hd).astype(dtype)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=ctx.mesh,
         in_specs=(P(bspec, None, None, None), P(bspec, axis, None, None),
                   P(bspec, axis, None, None), P(bspec, axis), P(bspec),
@@ -462,7 +461,7 @@ def moe_apply(x, p, moe_cfg, ctx):
         out = jax.lax.psum(out, model_axis)
         return out.reshape(Bl, Tl, D)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=ctx.mesh,
         in_specs=(P(batch_spec, None, None), P(None, None),
                   wg_spec, wu_spec, wd_spec),

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import MeshCtx
-from repro.parallel.compat import axis_size, shard_map
 
 
 def _quantize_int8(x):
@@ -44,7 +43,7 @@ def ring_allreduce_int8(x, axis: str):
     x: (N, ...) flat chunked tensor where N == axis size; each device owns
     the full tensor (DP-replicated grads) and the result is the mean.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     perm_fwd = [(i, (i + 1) % n) for i in range(n)]
 
@@ -107,7 +106,7 @@ def compressed_allreduce_tree(grads, ctx: MeshCtx):
         x = ring_allreduce_int8(x, inner)
         return jax.lax.pmean(x, outer)
 
-    out = shard_map(
+    out = jax.shard_map(
         f, mesh=mesh,
         in_specs=P(*(None,) * 2),
         out_specs=P(*(None,) * 2),
@@ -138,7 +137,7 @@ def hierarchical_psum_tree(grads, ctx: MeshCtx):
         return tuple(outs)
 
     leaves, treedef = jax.tree.flatten(grads)
-    outs = shard_map(
+    outs = jax.shard_map(
         f, mesh=ctx.mesh,
         in_specs=tuple(P() for _ in leaves),
         out_specs=tuple(P() for _ in leaves),

@@ -3,8 +3,10 @@
 The engine runs dense/GQA decoder LMs (the transformer family) with the
 paged decode path: per layer, the new token's K/V are appended to the
 sequence's pages (block-table write = the lba->pba map update) and decode
-attention gathers pages through the table (the Pallas kernel on TPU,
-interpret/ref on CPU).
+attention gathers pages through the table.  On a TPU that is always the
+paged-attention Pallas kernel, lowered to Mosaic; elsewhere the default is
+the jnp reference, and tests pass ``use_kernel=True`` to run the same
+kernel in interpret mode.
 
 Scheduling follows the paper's transit discipline:
   * finished / preempted sequences are *eagerly* packed to the host tier
@@ -15,10 +17,9 @@ Scheduling follows the paper's transit discipline:
   * a step "fsync" (``barrier``) completes all migrations before the batch
     shape changes.
 
-This is the host-driven reference engine (layer loop in Python, pools as
-per-layer arrays) — shaped for the CPU container and for tests; the mesh
-path for bulk decode lowers ``lm_decode_step`` with the dense ring cache
-(see launch/dryrun.py decode cells).
+The layer loop runs in Python over per-layer pools and eager ops: the
+same code serves the CPU tests (smoke widths) and the chip (full widths,
+``launch/serve.py --full``).
 """
 from __future__ import annotations
 
@@ -58,7 +59,7 @@ class PagedLM:
     """Paged decode path for the dense transformer family."""
 
     def __init__(self, cfg: ModelConfig, params, cache: PagedKVCache,
-                 use_kernel: bool = True) -> None:
+                 use_kernel: bool | None = None) -> None:
         assert cfg.family == "dense", "paged engine serves dense LMs"
         self.cfg = cfg
         self.params = params
@@ -288,7 +289,7 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, *,
                  cache_cfg: PagedCacheConfig | None = None,
                  max_batch: int = 8, eos_token: int = -1,
-                 use_kernel: bool = False, rng_seed: int = 0,
+                 use_kernel: bool | None = None, rng_seed: int = 0,
                  request_log: AsyncRequestLog | None = None,
                  autotune_every: int = 0,
                  pager=None, prefetch_depth: int = 2) -> None:

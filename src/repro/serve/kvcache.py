@@ -33,9 +33,10 @@ Mapping of the paper's structures:
                                  (int8) -> volume, exactly the paper's
                                  transit-cache -> PMem descent.
 
-The pool arrays live per layer: (P, page_size, Hkv, hd).  On TPU the decode
-attention resolves the table inside the Pallas kernel; on the CPU container
-the interpret-mode kernel (or the jnp ref) does the same resolution.
+The pool arrays live per layer: (P, page_size, Hkv, hd).  On a TPU the
+decode attention resolves the table inside the Pallas kernel (Mosaic); on
+the CPU the jnp ref, or the same kernel in interpret mode, does the same
+resolution.
 
 Concurrency contract: ``seq.table``, ``self._free``, the host tier and the
 active flags are guarded by ``_tlock`` — public entry points take it,
@@ -53,8 +54,8 @@ import numpy as np
 
 from repro.core.metrics import Metrics
 from repro.kernels import ref as kref
-from repro.kernels.ops import (gather_quantize_crc, paged_attention,
-                               scatter_dequantize_crc)
+from repro.kernels.ops import (gather_quantize_crc, on_tpu,
+                               paged_attention, scatter_dequantize_crc)
 from repro.volume.read_tier import ReadTier
 
 
@@ -72,6 +73,13 @@ class PagedCacheConfig:
     eager_eviction: bool = True
     conditional_bypass: bool = True
     read_tier_pages: int = 128    # dequantized-page cache (0 disables)
+
+    @property
+    def page_record_bytes(self) -> int:
+        """Bytes of one packed page as ``PagedKVCache._pack_page`` writes
+        it: per layer two crcs, then K and V as int8 rows + f32 scales."""
+        pg, D = self.page_size, self.n_kv_heads * self.head_dim
+        return self.n_layers * (8 + 2 * (pg * D + pg * 4))
 
 
 class HostTier:
@@ -627,11 +635,13 @@ class PagedKVCache:
                 entry[1]["v"][layer].astype(np.float32))   # host-fresh
 
     def attention(self, layer: int, q, sids: list[int], *,
-                  use_kernel: bool = True):
+                  use_kernel: bool | None = None):
         """q: (B, H, hd) one decode step for the given sequences.
 
         Fast path: every page HBM-resident AND every table within the
-        dense bound -> block-table kernel (lba->pba walk fused in).
+        dense bound -> block-table kernel (lba->pba walk fused in).  The
+        kernel is the default on a TPU; ``use_kernel`` overrides the
+        platform's choice (tests compare the kernel with the jnp ref).
         Slow path (pages bypassed to the host tier under pool pressure,
         or a sequence past max_pages_per_seq): materialize each
         sequence's KV from every tier — decode keeps running instead of
@@ -643,7 +653,7 @@ class PagedKVCache:
                        for sid in sids)
         if resident:
             table, lens = self.table_for(sids)
-            if use_kernel:
+            if on_tpu() if use_kernel is None else use_kernel:
                 return paged_attention(q, self.k_pool[layer],
                                        self.v_pool[layer], table, lens)
             return kref.paged_attention_ref(q, self.k_pool[layer],

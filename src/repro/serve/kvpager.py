@@ -41,6 +41,11 @@ from repro.core.metrics import Metrics
 _HDR = 8                      # 4B payload length + 4B crc32, little-endian
 
 
+def record_blocks(payload_len: int, block_size: int) -> int:
+    """Volume blocks one record of ``payload_len`` bytes occupies."""
+    return -(-(_HDR + payload_len) // block_size)
+
+
 class _Record:
     __slots__ = ("slot", "lba", "n_blocks", "key", "refs",
                  "spill_tickets", "pf_tickets")
@@ -86,9 +91,6 @@ class KVPager:
         self._next_handle = 0                    # handles never reused
 
     # ------------------------------------------------------------ geometry
-    def _blocks_for(self, payload_len: int) -> int:
-        return -(-(_HDR + payload_len) // self.block_size)
-
     def _init_slots(self, n_blocks: int) -> None:
         assert self._max_rec is None or n_blocks <= self._max_rec, \
             (f"KV page record of {n_blocks} blocks exceeds the device's "
@@ -117,7 +119,7 @@ class KVPager:
                 self._records[h].refs += 1
                 self.metrics.bump("kv_dedup_hits")
                 return h
-            n_blocks = self._blocks_for(len(payload))
+            n_blocks = record_blocks(len(payload), self.block_size)
             if self._slot_blocks is None:
                 self._init_slots(n_blocks)
             assert n_blocks <= self._slot_blocks, \

@@ -14,7 +14,10 @@ def _run(body: str) -> str:
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=8'\n" +
             textwrap.dedent(body))
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # the child runs on virtual CPU devices only: it must never contend
+    # for an accelerator that the parent process holds
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nERR:\n{out.stderr}"

@@ -382,3 +382,31 @@ def test_kv_paging_sim_sweep_invariants():
     assert x4["prefetch_hits"] > 0 and x4_sync["prefetch_hits"] == 0
     assert x4["restores_vol"] <= x4["spills"] + x4["dedup_hits"]
     assert x4 == run(n_sessions=32, **common)             # deterministic
+
+
+# ---------------------------------------------------------- record sizing
+@pytest.mark.parametrize("n_layers,page_size,head_dim", [
+    (2, 4, 8),
+    (8, 16, 128),        # a 17-block record: longer than a 16-deep window
+])
+def test_page_record_bytes_sizes_the_spill_tier(n_layers, page_size,
+                                                head_dim):
+    """``page_record_bytes`` is exactly what ``_pack_page`` writes, the
+    launcher's pager holds that many records of it at once, and its
+    in-flight window takes every record's prefetch chain."""
+    from repro.launch.serve import make_spill_pager
+    cfg = _cfg(n_layers=n_layers, page_size=page_size, head_dim=head_dim)
+    n_records = 3
+    pager = make_spill_pager(cfg, n_records)
+    c = PagedKVCache(cfg, pager=pager)
+    sid = c.new_sequence()
+    _fill(c, sid, page_size * n_records, np.random.default_rng(0))
+    c.deactivate(sid)                      # every page packed to the host
+    payloads = [c._pack_page(e[1]) for e in c.seqs[sid].table]
+    assert {len(p) for p in payloads} == {cfg.page_record_bytes}
+    handles = [pager.spill(p) for p in payloads]
+    assert pager.free_slots() == 0         # sized for exactly n_records
+    assert pager.prefetch(handles) == len(handles)
+    assert [pager.fetch(h) for h in handles] == payloads
+    assert pager.metrics.count["kv_prefetch_hits"] == len(handles)
+    pager.vol.close()

@@ -18,7 +18,10 @@ def _run(body: str) -> str:
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=8'\n" +
             textwrap.dedent(body))
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # the child runs on virtual CPU devices only: it must never contend
+    # for an accelerator that the parent process holds
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nERR:\n{out.stderr}"
@@ -50,7 +53,8 @@ def test_train_step_on_mesh_matches_single_device():
     step1 = jax.jit(make_train_step(model, opt))
     p1, o1, m1 = step1(params, opt_state, batch)
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ctx = make_ctx(mesh, 8)
     pspec = param_spec_tree(jax.eval_shape(lambda: params), mesh)
     pshard = named(pspec, mesh)
@@ -165,7 +169,10 @@ def test_moe_zero3_expert_gather_matches_single_device():
                                     jnp.int32)}
     ref = float(model.loss(params, batch))
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    # the model shards through GSPMD (with_sharding_constraint), so the
+    # mesh's axes are Auto; jax.make_mesh defaults to Explicit axes
+    mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ctx = make_ctx(mesh, 8)
     pspec = param_spec_tree(jax.eval_shape(lambda: params), mesh)
     # confirm the ZeRO-3 rule fired: expert F axis sharded over data
