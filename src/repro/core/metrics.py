@@ -65,13 +65,19 @@ the tail is the signal).  :class:`ShardScorer` turns a digest family
 penalty multiplier; ``tail_path()`` summarizes the hedged-read counters
 (``hedges_fired`` must equal ``hedges_won + hedges_cancelled`` — a
 hedge loser is cancelled, never abandoned).
+
+Spans: :meth:`Metrics.span` times a block into ``ns``/``count`` under
+its name and, while a JAX profile is recording, annotates the block in
+the profile under the same name, so the host's phases sit on one clock
+with the device's ops.  ``SERVE_SPANS`` lists the serving path's spans.
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import defaultdict
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 CATEGORIES = (
     "cache_metadata",
@@ -107,7 +113,7 @@ COMMIT_COUNTERS = (
 
 # Zero-copy data plane counters (PR 7) — bumped by the async engine's
 # registered-buffer pool / linked-SQE machinery and by the fused transit
-# kernel's callers; ``zerocopy_path()`` summarizes them:
+# kernel's restore path; ``zerocopy_path()`` summarizes them:
 #   copies_avoided       — submits that pinned a registered buffer (or
 #                          landed a read directly in one) instead of
 #                          taking a staging snapshot
@@ -118,9 +124,6 @@ COMMIT_COUNTERS = (
 #   links_submitted      — linked-SQE tickets (chained to a parent)
 #   link_cancelled       — dependents failed with ECANCELED by a parent
 #   link_depth_max       — deepest chain seen
-#   fused_kernel_passes  — fused transit-kernel launches (one VMEM pass
-#                          doing gather/scatter + int8 codec + checksum)
-#   fused_kernel_bytes   — packed payload bytes those passes moved
 #   transit_crc_errors   — restore checksums that failed verification
 ZEROCOPY_COUNTERS = (
     "copies_avoided",
@@ -130,8 +133,6 @@ ZEROCOPY_COUNTERS = (
     "links_submitted",
     "link_cancelled",
     "link_depth_max",
-    "fused_kernel_passes",
-    "fused_kernel_bytes",
     "transit_crc_errors",
 )
 
@@ -197,6 +198,29 @@ KV_PAGING_COUNTERS = (
 )
 
 
+# Serving-path spans (``Metrics.span``), all on the serve engine's shared
+# Metrics except ``vol.*``, which the volume's async workers time on the
+# volume's own.  Nesting: serve.step > {lm.decode > {kv.append,
+# kv.attention}, lm.prefill, kv.activate > kv.page_in > pager.fetch};
+# serve.suspend > {kv.page_out, kv.spill}.
+SERVE_SPANS = (
+    "serve.step",       # ServeEngine.step: prefetch, admit, decode, sample, retire
+    "serve.suspend",    # ServeEngine.suspend: a running session's pages move out
+    "lm.decode",        # PagedLM.decode_step: one token for every running sequence
+    "lm.prefill",       # PagedLM.prefill: one prompt through the model into pages
+    "kv.append",        # one layer's K/V writes for every sequence of a decode step
+    "kv.attention",     # PagedKVCache.attention: block table upload, attention call
+    "kv.page_out",      # one HBM page to the host tier: codec, copies, host.put
+    "kv.spill",         # host-tier overflow to the volume: pack, KVPager.spill, pops
+    "kv.activate",      # PagedKVCache.activate: a session's pages back into HBM
+    "kv.page_in",       # one page back into the pool, from the host tier or volume
+    "pager.fetch",      # KVPager.fetch: ticket waits, synchronous reads, wire crc
+    "vol.read",         # volume worker: one block read (prefetch or restore)
+    "vol.write",        # volume worker: one single-block record write
+    "vol.write_multi",  # volume worker: one chained multi-block record write
+)
+
+
 #: EWMA smoothing for :meth:`Metrics.observe` — ~the last 10-ish
 #: observations dominate, so a shard/node turning slow moves its average
 #: within tens of ops instead of being diluted by history
@@ -205,6 +229,13 @@ EWMA_ALPHA = 0.2
 #: raw samples kept per observe() key for the percentile digests — big
 #: enough for stable p99s, small enough to bound hot-path memory
 SVC_RING = 512
+
+
+def _annotation(name: str):
+    """A profiler annotation once JAX is loaded (only then can a profile
+    be recording); the host-only storage stack never imports JAX."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    return nullcontext() if prof is None else prof.TraceAnnotation(name)
 
 
 class Metrics:
@@ -222,15 +253,22 @@ class Metrics:
         self._svc_ring: dict[str, list] = {}
 
     @contextmanager
-    def timer(self, category: str):
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter_ns() - t0
-            with self._lock:
-                self.ns[category] += dt
-                self.count[category] += 1
+    def span(self, name: str):
+        """Time the block on the host clock into ``ns[name]`` and
+        ``count[name]``, and annotate it under ``name`` in a recording
+        JAX profile.  It never waits for the device: it measures the
+        host's time as the work happens."""
+        with _annotation(name):
+            t0 = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter_ns() - t0
+                with self._lock:
+                    self.ns[name] += dt
+                    self.count[name] += 1
+
+    timer = span
 
     def add_ns(self, category: str, ns: int) -> None:
         with self._lock:
@@ -334,7 +372,7 @@ class Metrics:
         return out
 
     def zerocopy_path(self) -> dict[str, float]:
-        """Zero-copy data-plane summary: pin/snapshot/link/fused-kernel
+        """Zero-copy data-plane summary: pin/snapshot/link/transit-crc
         counters plus ``pin_rate`` — the fraction of payload-carrying
         submits that crossed the engine without a copy."""
         with self._lock:

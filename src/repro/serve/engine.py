@@ -69,93 +69,96 @@ class PagedLM:
     def prefill(self, tokens: np.ndarray, sid: int) -> jnp.ndarray:
         """Run the prompt through the model, append K/V pages, return the
         last-token logits. tokens: (T,) one sequence."""
-        cfg, p = self.cfg, self.params
-        T = len(tokens)
-        tok = jnp.asarray(tokens, jnp.int32)[None]
-        x = jnp.take(p["embed"], tok, axis=0)
-        positions = jnp.arange(T, dtype=jnp.int32)[None]
-        kv_per_layer = []
-        for li in range(cfg.n_layers):
-            blk = _layer_params(p, li)
-            xn = apply_norm(x, blk["ln1"], cfg.norm)
-            q = (xn @ blk["attn"]["wq"]).reshape(1, T, cfg.n_heads, cfg.hd)
-            k = (xn @ blk["attn"]["wk"]).reshape(1, T, cfg.n_kv_heads, cfg.hd)
-            v = (xn @ blk["attn"]["wv"]).reshape(1, T, cfg.n_kv_heads, cfg.hd)
-            if "bq" in blk["attn"]:
-                q = q + blk["attn"]["bq"].reshape(1, 1, cfg.n_heads, cfg.hd)
-                k = k + blk["attn"]["bk"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
-                v = v + blk["attn"]["bv"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
-            if cfg.pos == "rope":
-                q = rope(q, positions, cfg.rope_theta)
-                k = rope(k, positions, cfg.rope_theta)
-            # dense causal attention for the prompt (prefill is compute-bound;
-            # pages are written below for the decode phase)
-            from repro.kernels.ref import flash_attention_ref
-            a = flash_attention_ref(q, k, v, causal=True,
-                                    window=cfg.attn_window)
-            x = x + a.reshape(1, T, -1) @ blk["attn"]["wo"]
-            h = apply_norm(x, blk["ln2"], cfg.norm)
-            from repro.models.layers import mlp_apply
-            x = x + mlp_apply(h, blk["mlp"], cfg.act)
-            kv_per_layer.append((k[0], v[0]))            # (T, Hkv, hd)
-        # append pages token-by-token (bulk write path)
-        for t in range(T):
-            self.cache.append_token(
-                sid,
-                [kv_per_layer[li][0][t] for li in range(cfg.n_layers)],
-                [kv_per_layer[li][1][t] for li in range(cfg.n_layers)])
-        x = apply_norm(x[:, -1:], p["final_norm"], cfg.norm)
-        w = p["embed"].T if cfg.tie_embeddings else p["head"]
-        return (x @ w).astype(jnp.float32)[0, 0]
+        with self.cache.metrics.span("lm.prefill"):
+            cfg, p = self.cfg, self.params
+            T = len(tokens)
+            tok = jnp.asarray(tokens, jnp.int32)[None]
+            x = jnp.take(p["embed"], tok, axis=0)
+            positions = jnp.arange(T, dtype=jnp.int32)[None]
+            kv_per_layer = []
+            for li in range(cfg.n_layers):
+                blk = _layer_params(p, li)
+                xn = apply_norm(x, blk["ln1"], cfg.norm)
+                q = (xn @ blk["attn"]["wq"]).reshape(1, T, cfg.n_heads, cfg.hd)
+                k = (xn @ blk["attn"]["wk"]).reshape(1, T, cfg.n_kv_heads, cfg.hd)
+                v = (xn @ blk["attn"]["wv"]).reshape(1, T, cfg.n_kv_heads, cfg.hd)
+                if "bq" in blk["attn"]:
+                    q = q + blk["attn"]["bq"].reshape(1, 1, cfg.n_heads, cfg.hd)
+                    k = k + blk["attn"]["bk"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
+                    v = v + blk["attn"]["bv"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
+                if cfg.pos == "rope":
+                    q = rope(q, positions, cfg.rope_theta)
+                    k = rope(k, positions, cfg.rope_theta)
+                # dense causal attention for the prompt (prefill is compute-bound;
+                # pages are written below for the decode phase)
+                from repro.kernels.ref import flash_attention_ref
+                a = flash_attention_ref(q, k, v, causal=True,
+                                        window=cfg.attn_window)
+                x = x + a.reshape(1, T, -1) @ blk["attn"]["wo"]
+                h = apply_norm(x, blk["ln2"], cfg.norm)
+                from repro.models.layers import mlp_apply
+                x = x + mlp_apply(h, blk["mlp"], cfg.act)
+                kv_per_layer.append((k[0], v[0]))            # (T, Hkv, hd)
+            # append pages token-by-token (bulk write path)
+            for t in range(T):
+                self.cache.append_token(
+                    sid,
+                    [kv_per_layer[li][0][t] for li in range(cfg.n_layers)],
+                    [kv_per_layer[li][1][t] for li in range(cfg.n_layers)])
+            x = apply_norm(x[:, -1:], p["final_norm"], cfg.norm)
+            w = p["embed"].T if cfg.tie_embeddings else p["head"]
+            return (x @ w).astype(jnp.float32)[0, 0]
 
     def decode_step(self, tokens: np.ndarray, sids: list[int],
                     positions: np.ndarray) -> jnp.ndarray:
         """One token for each running sequence. tokens: (B,), returns
         (B, V) logits."""
-        cfg, p = self.cfg, self.params
-        B = len(tokens)
-        tok = jnp.asarray(tokens, jnp.int32)[:, None]
-        pos = jnp.asarray(positions, jnp.int32)[:, None]
-        x = jnp.take(p["embed"], tok, axis=0)            # (B, 1, D)
-        new_kv = [[None] * cfg.n_layers for _ in range(B)]
-        for li in range(cfg.n_layers):
-            blk = _layer_params(p, li)
-            xn = apply_norm(x, blk["ln1"], cfg.norm)
-            q = (xn @ blk["attn"]["wq"]).reshape(B, 1, cfg.n_heads, cfg.hd)
-            k = (xn @ blk["attn"]["wk"]).reshape(B, 1, cfg.n_kv_heads, cfg.hd)
-            v = (xn @ blk["attn"]["wv"]).reshape(B, 1, cfg.n_kv_heads, cfg.hd)
-            if "bq" in blk["attn"]:
-                q = q + blk["attn"]["bq"].reshape(1, 1, cfg.n_heads, cfg.hd)
-                k = k + blk["attn"]["bk"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
-                v = v + blk["attn"]["bv"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
-            if cfg.pos == "rope":
-                q = rope(q, pos, cfg.rope_theta)
-                k = rope(k, pos, cfg.rope_theta)
-            for bi in range(B):
-                new_kv[bi][li] = (k[bi, 0], v[bi, 0])
-            # append THIS layer's kv before attending (token attends to self)
-            if li == 0:
-                for bi, sid in enumerate(sids):
-                    self.cache.append_token(
-                        sid, [new_kv[bi][L][0] if new_kv[bi][L] else
-                              jnp.zeros((cfg.n_kv_heads, cfg.hd), cfg.dtype)
-                              for L in range(cfg.n_layers)],
-                        [new_kv[bi][L][1] if new_kv[bi][L] else
-                         jnp.zeros((cfg.n_kv_heads, cfg.hd), cfg.dtype)
-                         for L in range(cfg.n_layers)])
-            else:
-                # layers >0: write into the already-appended slot
-                for bi, sid in enumerate(sids):
-                    self._overwrite_token(sid, li, new_kv[bi][li])
-            a = self.cache.attention(li, q[:, 0], sids,
-                                     use_kernel=self.use_kernel)
-            x = x + a.reshape(B, 1, -1) @ blk["attn"]["wo"]
-            h = apply_norm(x, blk["ln2"], cfg.norm)
-            from repro.models.layers import mlp_apply
-            x = x + mlp_apply(h, blk["mlp"], cfg.act)
-        x = apply_norm(x, p["final_norm"], cfg.norm)
-        w = p["embed"].T if cfg.tie_embeddings else p["head"]
-        return (x @ w).astype(jnp.float32)[:, 0]
+        with self.cache.metrics.span("lm.decode"):
+            cfg, p = self.cfg, self.params
+            B = len(tokens)
+            tok = jnp.asarray(tokens, jnp.int32)[:, None]
+            pos = jnp.asarray(positions, jnp.int32)[:, None]
+            x = jnp.take(p["embed"], tok, axis=0)            # (B, 1, D)
+            new_kv = [[None] * cfg.n_layers for _ in range(B)]
+            for li in range(cfg.n_layers):
+                blk = _layer_params(p, li)
+                xn = apply_norm(x, blk["ln1"], cfg.norm)
+                q = (xn @ blk["attn"]["wq"]).reshape(B, 1, cfg.n_heads, cfg.hd)
+                k = (xn @ blk["attn"]["wk"]).reshape(B, 1, cfg.n_kv_heads, cfg.hd)
+                v = (xn @ blk["attn"]["wv"]).reshape(B, 1, cfg.n_kv_heads, cfg.hd)
+                if "bq" in blk["attn"]:
+                    q = q + blk["attn"]["bq"].reshape(1, 1, cfg.n_heads, cfg.hd)
+                    k = k + blk["attn"]["bk"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
+                    v = v + blk["attn"]["bv"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
+                if cfg.pos == "rope":
+                    q = rope(q, pos, cfg.rope_theta)
+                    k = rope(k, pos, cfg.rope_theta)
+                for bi in range(B):
+                    new_kv[bi][li] = (k[bi, 0], v[bi, 0])
+                # append THIS layer's kv before attending (token attends to self)
+                with self.cache.metrics.span("kv.append"):
+                    if li == 0:
+                        for bi, sid in enumerate(sids):
+                            self.cache.append_token(
+                                sid, [new_kv[bi][L][0] if new_kv[bi][L] else
+                                      jnp.zeros((cfg.n_kv_heads, cfg.hd), cfg.dtype)
+                                      for L in range(cfg.n_layers)],
+                                [new_kv[bi][L][1] if new_kv[bi][L] else
+                                 jnp.zeros((cfg.n_kv_heads, cfg.hd), cfg.dtype)
+                                 for L in range(cfg.n_layers)])
+                    else:
+                        # layers >0: write into the already-appended slot
+                        for bi, sid in enumerate(sids):
+                            self._overwrite_token(sid, li, new_kv[bi][li])
+                a = self.cache.attention(li, q[:, 0], sids,
+                                         use_kernel=self.use_kernel)
+                x = x + a.reshape(B, 1, -1) @ blk["attn"]["wo"]
+                h = apply_norm(x, blk["ln2"], cfg.norm)
+                from repro.models.layers import mlp_apply
+                x = x + mlp_apply(h, blk["mlp"], cfg.act)
+            x = apply_norm(x, p["final_norm"], cfg.norm)
+            w = p["embed"].T if cfg.tie_embeddings else p["head"]
+            return (x @ w).astype(jnp.float32)[:, 0]
 
     def _overwrite_token(self, sid: int, layer: int, kv) -> None:
         # delegated: the cache serializes the pool/table write on _tlock
@@ -336,10 +339,11 @@ class ServeEngine:
         """Preempt a running request: its pages eagerly transit out
         (host tier, then the volume once the host budget overflows);
         ``_admit`` resumes it ahead of fresh prompts."""
-        self.running.remove(req)
-        self.cache.deactivate(req.seq_id)
-        self.suspended.append(req)
-        self.metrics.bump("suspends")
+        with self.metrics.span("serve.suspend"):
+            self.running.remove(req)
+            self.cache.deactivate(req.seq_id)
+            self.suspended.append(req)
+            self.metrics.bump("suspends")
 
     def _prefetch_ahead(self) -> None:
         """Decode-ahead restore: linked async reads for the next
@@ -393,27 +397,28 @@ class ServeEngine:
 
     def step(self) -> int:
         """One scheduler tick: admit, decode one token for every runner."""
-        self._prefetch_ahead()
-        self._admit()
-        if not self.running:
-            return 0
-        reqs = self.running
-        tokens = np.asarray([r.out_tokens[-1] for r in reqs], np.int64)
-        positions = np.asarray([len(r.prompt) + len(r.out_tokens) - 1
-                                for r in reqs], np.int64)
-        logits = self.lm.decode_step(tokens, [r.seq_id for r in reqs],
-                                     positions)
-        nxt = self._sample(logits, reqs)
-        still = []
-        for req, tok in zip(reqs, nxt):
-            req.out_tokens.append(int(tok))
-            if (len(req.out_tokens) >= req.max_new_tokens
-                    or tok == self.eos):
-                self._retire(req)
-            else:
-                still.append(req)
-        self.running = still
-        return len(reqs)
+        with self.metrics.span("serve.step"):
+            self._prefetch_ahead()
+            self._admit()
+            if not self.running:
+                return 0
+            reqs = self.running
+            tokens = np.asarray([r.out_tokens[-1] for r in reqs], np.int64)
+            positions = np.asarray([len(r.prompt) + len(r.out_tokens) - 1
+                                    for r in reqs], np.int64)
+            logits = self.lm.decode_step(tokens, [r.seq_id for r in reqs],
+                                         positions)
+            nxt = self._sample(logits, reqs)
+            still = []
+            for req, tok in zip(reqs, nxt):
+                req.out_tokens.append(int(tok))
+                if (len(req.out_tokens) >= req.max_new_tokens
+                        or tok == self.eos):
+                    self._retire(req)
+                else:
+                    still.append(req)
+            self.running = still
+            return len(reqs)
 
     def _autotune_tick(self) -> None:
         if self.autotune_every <= 0 or self.request_log is None:

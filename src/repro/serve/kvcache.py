@@ -216,9 +216,8 @@ class PagedKVCache:
                                           self._host_fresh_page()))
                         self._maybe_spill_locked()
                     else:
-                        with self.metrics.timer("cache_eviction_and_write"):
-                            if not self._evict_coldest_locked():
-                                raise MemoryError("KV pool exhausted")
+                        if not self._evict_coldest_locked():
+                            raise MemoryError("KV pool exhausted")
                         self._maybe_spill_locked()
                         page = self._alloc_page()
                         seq.table.append(("hbm", page))
@@ -271,27 +270,26 @@ class PagedKVCache:
         gather + int8 pack + wire checksum in one VMEM pass (the old
         path quantized, then walked the packed bytes again on the host
         for the checksum)."""
-        kind, page = seq.table[logical]
-        assert kind == "hbm"
-        handles = []
-        ids = jnp.array([page], jnp.int32)
-        for li in range(self.cfg.n_layers):
-            pool_k = self.k_pool[li].reshape(self.cfg.n_pages,
-                                             self.cfg.page_size, -1)
-            pool_v = self.v_pool[li].reshape(self.cfg.n_pages,
-                                             self.cfg.page_size, -1)
-            qk, sk, ck = gather_quantize_crc(pool_k, ids)
-            qv, sv, cv = gather_quantize_crc(pool_v, ids)
-            hk = self.host.put(li, np.asarray(qk[0]), np.asarray(sk[0]),
-                               int(ck[0]))
-            hv = self.host.put(li, np.asarray(qv[0]), np.asarray(sv[0]),
-                               int(cv[0]))
-            self.metrics.bump("fused_kernel_passes", 2)
-            self.metrics.bump("fused_kernel_bytes", qk.nbytes + qv.nbytes)
-            handles.append((hk, hv))
-        seq.table[logical] = ("host", handles)
-        self._free.append(page)
-        self.metrics.bump("pages_out")
+        with self.metrics.span("kv.page_out"):
+            kind, page = seq.table[logical]
+            assert kind == "hbm"
+            handles = []
+            ids = jnp.array([page], jnp.int32)
+            for li in range(self.cfg.n_layers):
+                pool_k = self.k_pool[li].reshape(self.cfg.n_pages,
+                                                 self.cfg.page_size, -1)
+                pool_v = self.v_pool[li].reshape(self.cfg.n_pages,
+                                                 self.cfg.page_size, -1)
+                qk, sk, ck = gather_quantize_crc(pool_k, ids)
+                qv, sv, cv = gather_quantize_crc(pool_v, ids)
+                hk = self.host.put(li, np.asarray(qk[0]), np.asarray(sk[0]),
+                                   int(ck[0]))
+                hv = self.host.put(li, np.asarray(qv[0]), np.asarray(sv[0]),
+                                   int(cv[0]))
+                handles.append((hk, hv))
+            seq.table[logical] = ("host", handles)
+            self._free.append(page)
+            self.metrics.bump("pages_out")
 
     # ------------------------------------------------------ volume spill tier
     def host_page_count(self) -> int:
@@ -349,28 +347,29 @@ class PagedKVCache:
         Host-fresh pages (raw f32, still being written) never spill."""
         if self.pager is None:
             return
-        while self.host_page_count() > self.cfg.host_pages:
-            victim = None
-            for seq in self.seqs.values():               # oldest sid first
-                if seq.active:
-                    continue
-                for li, entry in enumerate(seq.table):
-                    if entry[0] == "host":
-                        victim = (seq, li, entry[1])
+        with self.metrics.span("kv.spill"):
+            while self.host_page_count() > self.cfg.host_pages:
+                victim = None
+                for seq in self.seqs.values():               # oldest sid first
+                    if seq.active:
+                        continue
+                    for li, entry in enumerate(seq.table):
+                        if entry[0] == "host":
+                            victim = (seq, li, entry[1])
+                            break
+                    if victim is not None:
                         break
-                if victim is not None:
-                    break
-            if victim is None:                           # all hot: tolerate
-                return
-            seq, li, handles = victim
-            payload = self._pack_page(handles)
-            handle = self.pager.spill(payload)
-            for lj, (hk, hv) in enumerate(handles):
-                if self.read_tier is not None:
-                    self.read_tier.invalidate(("page", lj, hk, hv))
-                self.host.pop(lj, hk)
-                self.host.pop(lj, hv)
-            seq.table[li] = ("vol", handle)
+                if victim is None:                           # all hot: tolerate
+                    return
+                seq, li, handles = victim
+                payload = self._pack_page(handles)
+                handle = self.pager.spill(payload)
+                for lj, (hk, hv) in enumerate(handles):
+                    if self.read_tier is not None:
+                        self.read_tier.invalidate(("page", lj, hk, hv))
+                    self.host.pop(lj, hk)
+                    self.host.pop(lj, hv)
+                seq.table[li] = ("vol", handle)
 
     def prefetch(self, sid: int) -> int:
         """Decode-ahead restore for a suspended sequence: issue linked
@@ -396,68 +395,66 @@ class PagedKVCache:
         mismatch the allocated pool page goes back to the free list and
         the host entries stay put (nothing is popped until the whole
         page verified) — an IOError never leaks pool capacity."""
-        kind, payload = seq.table[logical]
-        if kind == "vol":
-            raw = self.pager.fetch(payload)              # may raise IOError
-            handles = []
-            for li, (qk, sk, ck, qv, sv, cv) in \
-                    enumerate(self._unpack_page(raw)):
-                handles.append((self.host.put(li, qk, sk, ck),
-                                self.host.put(li, qv, sv, cv)))
-            self.pager.release(payload)
-            seq.table[logical] = ("host", handles)
-            kind, payload = "host", handles
-        page = self._alloc_page()
-        if page is None:
-            return False
-        pg, H, hd = self.cfg.page_size, self.cfg.n_kv_heads, self.cfg.head_dim
-        if kind == "host":
-            ids = jnp.array([page], jnp.int32)
-            new_k, new_v = [], []
-            try:
-                for li, (hk, hv) in enumerate(payload):
-                    qk, sk, ck = self.host.get(li, hk)
-                    qv, sv, cv = self.host.get(li, hv)
-                    pool_k = self.k_pool[li].reshape(self.cfg.n_pages, pg, -1)
-                    pool_v = self.v_pool[li].reshape(self.cfg.n_pages, pg, -1)
-                    # fused restore: dequantize+scatter AND checksum the int8
-                    # payload as received, in the same pass — verified against
-                    # the spill-time value before the page goes live
-                    pool_k, rck = scatter_dequantize_crc(
-                        pool_k, ids, jnp.asarray(qk)[None],
-                        jnp.asarray(sk)[None])
-                    pool_v, rcv = scatter_dequantize_crc(
-                        pool_v, ids, jnp.asarray(qv)[None],
-                        jnp.asarray(sv)[None])
-                    self.metrics.bump("fused_kernel_passes", 2)
-                    self.metrics.bump("fused_kernel_bytes",
-                                      qk.nbytes + qv.nbytes)
-                    if int(rck[0]) != ck or int(rcv[0]) != cv:
-                        self.metrics.bump("transit_crc_errors")
-                        raise IOError(
-                            f"KV transit checksum mismatch: layer {li} page "
-                            f"{logical} of seq {seq.seq_id} tore in transit")
-                    new_k.append(pool_k.reshape(self.cfg.n_pages, pg, H, hd))
-                    new_v.append(pool_v.reshape(self.cfg.n_pages, pg, H, hd))
-            except IOError:
-                self._free.append(page)                  # no capacity leak
-                raise
-            for li, (hk, hv) in enumerate(payload):      # verified: commit
-                if self.read_tier is not None:
-                    self.read_tier.invalidate(("page", li, hk, hv))
-                self.host.pop(li, hk)
-                self.host.pop(li, hv)
-                self.k_pool[li] = new_k[li]
-                self.v_pool[li] = new_v[li]
-        else:                                            # host-fresh (raw f32)
-            for li in range(self.cfg.n_layers):
-                self.k_pool[li] = self.k_pool[li].at[page].set(
-                    jnp.asarray(payload["k"][li], self.cfg.dtype))
-                self.v_pool[li] = self.v_pool[li].at[page].set(
-                    jnp.asarray(payload["v"][li], self.cfg.dtype))
-        seq.table[logical] = ("hbm", page)
-        self.metrics.bump("pages_in")
-        return True
+        with self.metrics.span("kv.page_in"):
+            kind, payload = seq.table[logical]
+            if kind == "vol":
+                raw = self.pager.fetch(payload)              # may raise IOError
+                handles = []
+                for li, (qk, sk, ck, qv, sv, cv) in \
+                        enumerate(self._unpack_page(raw)):
+                    handles.append((self.host.put(li, qk, sk, ck),
+                                    self.host.put(li, qv, sv, cv)))
+                self.pager.release(payload)
+                seq.table[logical] = ("host", handles)
+                kind, payload = "host", handles
+            page = self._alloc_page()
+            if page is None:
+                return False
+            pg, H, hd = self.cfg.page_size, self.cfg.n_kv_heads, self.cfg.head_dim
+            if kind == "host":
+                ids = jnp.array([page], jnp.int32)
+                new_k, new_v = [], []
+                try:
+                    for li, (hk, hv) in enumerate(payload):
+                        qk, sk, ck = self.host.get(li, hk)
+                        qv, sv, cv = self.host.get(li, hv)
+                        pool_k = self.k_pool[li].reshape(self.cfg.n_pages, pg, -1)
+                        pool_v = self.v_pool[li].reshape(self.cfg.n_pages, pg, -1)
+                        # fused restore: dequantize+scatter AND checksum the int8
+                        # payload as received, in the same pass — verified against
+                        # the spill-time value before the page goes live
+                        pool_k, rck = scatter_dequantize_crc(
+                            pool_k, ids, jnp.asarray(qk)[None],
+                            jnp.asarray(sk)[None])
+                        pool_v, rcv = scatter_dequantize_crc(
+                            pool_v, ids, jnp.asarray(qv)[None],
+                            jnp.asarray(sv)[None])
+                        if int(rck[0]) != ck or int(rcv[0]) != cv:
+                            self.metrics.bump("transit_crc_errors")
+                            raise IOError(
+                                f"KV transit checksum mismatch: layer {li} page "
+                                f"{logical} of seq {seq.seq_id} tore in transit")
+                        new_k.append(pool_k.reshape(self.cfg.n_pages, pg, H, hd))
+                        new_v.append(pool_v.reshape(self.cfg.n_pages, pg, H, hd))
+                except IOError:
+                    self._free.append(page)                  # no capacity leak
+                    raise
+                for li, (hk, hv) in enumerate(payload):      # verified: commit
+                    if self.read_tier is not None:
+                        self.read_tier.invalidate(("page", li, hk, hv))
+                    self.host.pop(li, hk)
+                    self.host.pop(li, hv)
+                    self.k_pool[li] = new_k[li]
+                    self.v_pool[li] = new_v[li]
+            else:                                            # host-fresh (raw f32)
+                for li in range(self.cfg.n_layers):
+                    self.k_pool[li] = self.k_pool[li].at[page].set(
+                        jnp.asarray(payload["k"][li], self.cfg.dtype))
+                    self.v_pool[li] = self.v_pool[li].at[page].set(
+                        jnp.asarray(payload["v"][li], self.cfg.dtype))
+            seq.table[logical] = ("hbm", page)
+            self.metrics.bump("pages_in")
+            return True
 
     def deactivate(self, sid: int) -> None:
         """Sequence paused/finished: eagerly transit its pages out.
@@ -538,16 +535,17 @@ class PagedKVCache:
 
         Raises TimeoutError if the eviction barrier expires (page-outs
         still in flight — proceeding would race their table writes)."""
-        if self._evict_pool is not None:
-            self.drain_evictions()
-        with self._tlock:
-            seq = self.seqs[sid]
-            seq.active = True
-            for li, entry in enumerate(seq.table):
-                if entry[0] in ("host", "host-fresh", "vol"):
-                    if not self._page_in_locked(seq, li):
-                        self.metrics.bump("activate_stalls")
-                        return                            # partial: retry later
+        with self.metrics.span("kv.activate"):
+            if self._evict_pool is not None:
+                self.drain_evictions()
+            with self._tlock:
+                seq = self.seqs[sid]
+                seq.active = True
+                for li, entry in enumerate(seq.table):
+                    if entry[0] in ("host", "host-fresh", "vol"):
+                        if not self._page_in_locked(seq, li):
+                            self.metrics.bump("activate_stalls")
+                            return                            # partial: retry later
 
     def release(self, sid: int) -> None:
         with self._tlock:
@@ -647,38 +645,39 @@ class PagedKVCache:
         sequence's KV from every tier — decode keeps running instead of
         stalling on page-in, the serving analogue of Caiti's conditional
         bypass."""
-        mp = self.cfg.max_pages_per_seq
-        resident = all(len(self.seqs[sid].table) <= mp
-                       and all(e[0] == "hbm" for e in self.seqs[sid].table)
-                       for sid in sids)
-        if resident:
-            table, lens = self.table_for(sids)
-            if on_tpu() if use_kernel is None else use_kernel:
-                return paged_attention(q, self.k_pool[layer],
-                                       self.v_pool[layer], table, lens)
-            return kref.paged_attention_ref(q, self.k_pool[layer],
-                                            self.v_pool[layer], table, lens)
-        self.metrics.bump("hybrid_attention")
-        pg, H, hd = self.cfg.page_size, self.cfg.n_kv_heads, self.cfg.head_dim
-        B = len(sids)
-        with self._tlock:
-            S = max(len(self.seqs[s].table) for s in sids) * pg
-            k = np.zeros((B, S, H, hd), np.float32)
-            v = np.zeros((B, S, H, hd), np.float32)
-            lens = np.zeros((B,), np.int32)
-            for bi, sid in enumerate(sids):
-                seq = self.seqs[sid]
-                lens[bi] = seq.length
-                for li, entry in enumerate(seq.table):
-                    pk, pv = self._page_kv(layer, entry)
-                    k[bi, li * pg:(li + 1) * pg] = pk
-                    v[bi, li * pg:(li + 1) * pg] = pv
-        # single-"page" ref attention over the materialized view
-        kpool = jnp.asarray(k).reshape(B * 1, S, H, hd)
-        vpool = jnp.asarray(v).reshape(B * 1, S, H, hd)
-        table = jnp.arange(B, dtype=jnp.int32)[:, None]
-        return kref.paged_attention_ref(q, kpool, vpool, table,
-                                        jnp.asarray(lens))
+        with self.metrics.span("kv.attention"):
+            mp = self.cfg.max_pages_per_seq
+            resident = all(len(self.seqs[sid].table) <= mp
+                           and all(e[0] == "hbm" for e in self.seqs[sid].table)
+                           for sid in sids)
+            if resident:
+                table, lens = self.table_for(sids)
+                if on_tpu() if use_kernel is None else use_kernel:
+                    return paged_attention(q, self.k_pool[layer],
+                                           self.v_pool[layer], table, lens)
+                return kref.paged_attention_ref(q, self.k_pool[layer],
+                                                self.v_pool[layer], table, lens)
+            self.metrics.bump("hybrid_attention")
+            pg, H, hd = self.cfg.page_size, self.cfg.n_kv_heads, self.cfg.head_dim
+            B = len(sids)
+            with self._tlock:
+                S = max(len(self.seqs[s].table) for s in sids) * pg
+                k = np.zeros((B, S, H, hd), np.float32)
+                v = np.zeros((B, S, H, hd), np.float32)
+                lens = np.zeros((B,), np.int32)
+                for bi, sid in enumerate(sids):
+                    seq = self.seqs[sid]
+                    lens[bi] = seq.length
+                    for li, entry in enumerate(seq.table):
+                        pk, pv = self._page_kv(layer, entry)
+                        k[bi, li * pg:(li + 1) * pg] = pk
+                        v[bi, li * pg:(li + 1) * pg] = pv
+            # single-"page" ref attention over the materialized view
+            kpool = jnp.asarray(k).reshape(B * 1, S, H, hd)
+            vpool = jnp.asarray(v).reshape(B * 1, S, H, hd)
+            table = jnp.arange(B, dtype=jnp.int32)[:, None]
+            return kref.paged_attention_ref(q, kpool, vpool, table,
+                                            jnp.asarray(lens))
 
     # ---------------------------------------------------------------- stats
     def occupancy(self) -> float:

@@ -196,47 +196,48 @@ class KVPager:
         chain landed, synchronous reads otherwise), verify the wire crc,
         and return the packed payload.  The record stays live — pair
         with :meth:`release` once the page is resident again."""
-        with self._lock:
-            rec = self._records[handle]
-            spills, rec.spill_tickets = rec.spill_tickets, []
-            pf, rec.pf_tickets = rec.pf_tickets, None
-        for t in spills:                        # settle the write first
-            self.vol.wait(t)
-            if t.error is not None:
-                raise t.error
-        raw = None
-        if pf is not None:
-            ok = True
-            parts = []
-            for t in pf:
-                self.vol.wait(t)
-                if t.error is not None:         # link cancelled / device
-                    ok = False
-                else:
-                    parts.append(self._as_bytes(t.value))
-            if ok:
-                raw = b"".join(parts)
-                self.metrics.bump("kv_prefetch_hits")
-        if raw is None:                         # sync restore path
-            parts = []
-            for i in range(rec.n_blocks):
-                t = self.vol.submit("read", rec.lba + i,
-                                    tenant=self.tenant, block=True)
+        with self.metrics.span("pager.fetch"):
+            with self._lock:
+                rec = self._records[handle]
+                spills, rec.spill_tickets = rec.spill_tickets, []
+                pf, rec.pf_tickets = rec.pf_tickets, None
+            for t in spills:                        # settle the write first
                 self.vol.wait(t)
                 if t.error is not None:
                     raise t.error
-                parts.append(self._as_bytes(t.value))
-            raw = b"".join(parts)
-        n = int.from_bytes(raw[:4], "little")
-        crc = int.from_bytes(raw[4:8], "little")
-        payload = raw[_HDR:_HDR + n]
-        if len(payload) != n or zlib.crc32(payload) != crc:
-            self.metrics.bump("kv_restore_crc_errors")
-            raise IOError(
-                f"KV spill record {handle} failed its wire checksum on "
-                f"restore (lba {rec.lba}, {rec.n_blocks} blocks)")
-        self.metrics.bump("kv_restores")
-        return payload
+            raw = None
+            if pf is not None:
+                ok = True
+                parts = []
+                for t in pf:
+                    self.vol.wait(t)
+                    if t.error is not None:         # link cancelled / device
+                        ok = False
+                    else:
+                        parts.append(self._as_bytes(t.value))
+                if ok:
+                    raw = b"".join(parts)
+                    self.metrics.bump("kv_prefetch_hits")
+            if raw is None:                         # sync restore path
+                parts = []
+                for i in range(rec.n_blocks):
+                    t = self.vol.submit("read", rec.lba + i,
+                                        tenant=self.tenant, block=True)
+                    self.vol.wait(t)
+                    if t.error is not None:
+                        raise t.error
+                    parts.append(self._as_bytes(t.value))
+                raw = b"".join(parts)
+            n = int.from_bytes(raw[:4], "little")
+            crc = int.from_bytes(raw[4:8], "little")
+            payload = raw[_HDR:_HDR + n]
+            if len(payload) != n or zlib.crc32(payload) != crc:
+                self.metrics.bump("kv_restore_crc_errors")
+                raise IOError(
+                    f"KV spill record {handle} failed its wire checksum on "
+                    f"restore (lba {rec.lba}, {rec.n_blocks} blocks)")
+            self.metrics.bump("kv_restores")
+            return payload
 
     def _cancel(self, t) -> None:
         """Best-effort cancel + settle (the facade only exposes cancel

@@ -87,6 +87,7 @@ import itertools
 import threading
 import time
 from collections import deque
+from contextlib import nullcontext
 
 import numpy as np
 
@@ -753,9 +754,13 @@ class AsyncIOEngine:
     def _execute(self, t: Ticket) -> None:
         data, blocks = t.value if isinstance(t.value, tuple) else (None, None)
         t.value = None
+        m = getattr(self.vol, "metrics", None)
         t0 = time.perf_counter_ns()
         try:
-            val = self._run_op(t, data, blocks)
+            # the op's span puts this worker thread's service time on its
+            # own line of a recording profile, beside the submitter's
+            with m.span(f"vol.{t.op}") if m is not None else nullcontext():
+                val = self._run_op(t, data, blocks)
         except SimulatedCrash as e:
             # power loss: the whole ring dies with the machine
             self._fatal(e, t)

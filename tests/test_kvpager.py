@@ -410,3 +410,43 @@ def test_page_record_bytes_sizes_the_spill_tier(n_layers, page_size,
     assert [pager.fetch(h) for h in handles] == payloads
     assert pager.metrics.count["kv_prefetch_hits"] == len(handles)
     pager.vol.close()
+
+
+def test_engine_spans_count_where_the_work_happens():
+    """Every serving span is entered on the engine's shared Metrics (the
+    volume ops on the volume's own): one ``kv.append`` and one
+    ``kv.attention`` per layer per decode step, one page span per page
+    moved, one ``pager.fetch`` per volume restore."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.core.metrics import SERVE_SPANS
+    from repro.models import build_model
+    from repro.serve import ServeEngine
+
+    cfg = get_config("qwen2.5-3b", smoke=True)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    vol = _vol(n_lbas=4096)
+    cache_cfg = PagedCacheConfig(
+        n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
+        page_size=4, n_pages=16, host_pages=0, max_pages_per_seq=16)
+    eng = ServeEngine(cfg, params, cache_cfg=cache_cfg, max_batch=2,
+                      pager=KVPager(vol, capacity_blocks=2048))
+    eng.submit(list(range(2, 14)), max_new_tokens=6)
+    eng.submit(list(range(3, 15)), max_new_tokens=6)
+    eng.step()
+    eng.suspend(eng.running[0])
+    eng.run(max_ticks=200)
+    c = eng.metrics.count
+    assert c["lm.decode"] >= 5 and c["lm.prefill"] == 2
+    assert c["kv.append"] == c["kv.attention"] == cfg.n_layers * c["lm.decode"]
+    assert c["serve.suspend"] == 1 and c["serve.step"] >= c["lm.decode"]
+    assert c["kv.page_out"] == c["pages_out"] > 0
+    assert c["kv.page_in"] == c["pages_in"] > 0
+    assert c["pager.fetch"] == c["kv_restores"] > 0
+    assert c["kv.activate"] == c["resumes"] and c["kv.spill"] > 0
+    vc = vol.metrics.count
+    assert vc["vol.read"] > 0 and vc["vol.write"] + vc["vol.write_multi"] > 0
+    on_engine = {n for n in SERVE_SPANS if not n.startswith("vol.")}
+    assert all(eng.metrics.ns[n] > 0 for n in on_engine)
+    assert not {"fused_kernel_passes", "cache_eviction_and_write"} & set(c)
