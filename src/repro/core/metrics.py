@@ -220,6 +220,13 @@ SERVE_SPANS = (
     "vol.write_multi",  # volume worker: one chained multi-block record write
 )
 
+# Serving-path counter beside the spans, on the same shared Metrics:
+#   lm.dense_traces — traces of PagedLM's jitted dense math (embedding,
+#                     attention input, attention output, head), bumped in
+#                     each function's body, which runs only while JAX
+#                     traces: four per batch shape over a run, never one
+#                     per layer or per step
+
 
 #: EWMA smoothing for :meth:`Metrics.observe` — ~the last 10-ish
 #: observations dominate, so a shard/node turning slow moves its average

@@ -17,9 +17,10 @@ Scheduling follows the paper's transit discipline:
   * a step "fsync" (``barrier``) completes all migrations before the batch
     shape changes.
 
-The layer loop runs in Python over per-layer pools and eager ops: the
-same code serves the CPU tests (smoke widths) and the chip (full widths,
-``launch/serve.py --full``).
+The decode step's layer loop runs in Python over per-layer pools: each
+layer's dense math is two jitted calls around the cache's appends and
+attention.  Prefill is eager ops.  The same code serves the CPU tests
+(smoke widths) and the chip (full widths, ``launch/serve.py --full``).
 """
 from __future__ import annotations
 
@@ -33,7 +34,7 @@ import numpy as np
 
 from repro.core.metrics import Metrics
 from repro.models.common import ModelConfig
-from repro.models.layers import apply_norm, rope
+from repro.models.layers import apply_norm, mlp_apply, rope
 from .kvcache import PagedCacheConfig, PagedKVCache
 
 
@@ -55,6 +56,60 @@ def _layer_params(params, i: int):
     return jax.tree.map(lambda a: a[i], params["blocks"])
 
 
+def _dense_step_fns(cfg: ModelConfig, bump):
+    """The dense math of one decode step as four jitted functions: the
+    embedding, each layer's attention input and output halves, and the
+    head.  ``cfg`` is closed over (static); every weight is an argument,
+    and the layer index is a traced int32, so one executable per batch
+    shape serves every layer.  Without excess precision every op rounds
+    to its dtype as an eager op does, so the logits are those of the
+    eager ops, bit for bit.  Each body bumps ``lm.dense_traces``: it runs
+    only while JAX traces."""
+    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+
+    def embed(table, tokens):
+        bump("lm.dense_traces")
+        return jnp.take(table, tokens[:, None], axis=0)      # (B, 1, D)
+
+    def attn_in(blocks, li, x, pos):
+        """ln1, QKV (+bias), RoPE -> q (B, H, hd) and B per-sequence
+        (Hkv, hd) keys and values, as the cache's appends take them."""
+        bump("lm.dense_traces")
+        blk = jax.tree.map(lambda a: a[li], blocks)
+        B = x.shape[0]
+        xn = apply_norm(x, blk["ln1"], cfg.norm)
+        q = (xn @ blk["attn"]["wq"]).reshape(B, 1, H, hd)
+        k = (xn @ blk["attn"]["wk"]).reshape(B, 1, Hkv, hd)
+        v = (xn @ blk["attn"]["wv"]).reshape(B, 1, Hkv, hd)
+        if "bq" in blk["attn"]:
+            q = q + blk["attn"]["bq"].reshape(1, 1, H, hd)
+            k = k + blk["attn"]["bk"].reshape(1, 1, Hkv, hd)
+            v = v + blk["attn"]["bv"].reshape(1, 1, Hkv, hd)
+        if cfg.pos == "rope":
+            q = rope(q, pos[:, None], cfg.rope_theta)
+            k = rope(k, pos[:, None], cfg.rope_theta)
+        return (q[:, 0], tuple(k[bi, 0] for bi in range(B)),
+                tuple(v[bi, 0] for bi in range(B)))
+
+    def attn_out(blocks, li, x, a):
+        """wo and the residual, ln2, the MLP and its residual."""
+        bump("lm.dense_traces")
+        blk = jax.tree.map(lambda w: w[li], blocks)
+        x = x + a.reshape(x.shape[0], 1, -1) @ blk["attn"]["wo"]
+        h = apply_norm(x, blk["ln2"], cfg.norm)
+        return x + mlp_apply(h, blk["mlp"], cfg.act)
+
+    def head(final_norm, w, x):
+        bump("lm.dense_traces")
+        x = apply_norm(x, final_norm, cfg.norm)
+        w = w.T if cfg.tie_embeddings else w
+        return (x @ w).astype(jnp.float32)[:, 0]              # (B, V)
+
+    opts = {"xla_allow_excess_precision": False}
+    return tuple(jax.jit(f, compiler_options=opts)
+                 for f in (embed, attn_in, attn_out, head))
+
+
 class PagedLM:
     """Paged decode path for the dense transformer family."""
 
@@ -65,6 +120,10 @@ class PagedLM:
         self.params = params
         self.cache = cache
         self.use_kernel = use_kernel
+        (self._embed, self._attn_in, self._attn_out,
+         self._head) = _dense_step_fns(cfg, cache.metrics.bump)
+        self._layer_ids = [jnp.asarray(i, jnp.int32)
+                           for i in range(cfg.n_layers)]
 
     def prefill(self, tokens: np.ndarray, sid: int) -> jnp.ndarray:
         """Run the prompt through the model, append K/V pages, return the
@@ -96,7 +155,6 @@ class PagedLM:
                                         window=cfg.attn_window)
                 x = x + a.reshape(1, T, -1) @ blk["attn"]["wo"]
                 h = apply_norm(x, blk["ln2"], cfg.norm)
-                from repro.models.layers import mlp_apply
                 x = x + mlp_apply(h, blk["mlp"], cfg.act)
                 kv_per_layer.append((k[0], v[0]))            # (T, Hkv, hd)
             # append pages token-by-token (bulk write path)
@@ -116,25 +174,14 @@ class PagedLM:
         with self.cache.metrics.span("lm.decode"):
             cfg, p = self.cfg, self.params
             B = len(tokens)
-            tok = jnp.asarray(tokens, jnp.int32)[:, None]
-            pos = jnp.asarray(positions, jnp.int32)[:, None]
-            x = jnp.take(p["embed"], tok, axis=0)            # (B, 1, D)
+            blocks = p["blocks"]
+            x = self._embed(p["embed"], jnp.asarray(tokens, jnp.int32))
+            pos = jnp.asarray(positions, jnp.int32)
             new_kv = [[None] * cfg.n_layers for _ in range(B)]
-            for li in range(cfg.n_layers):
-                blk = _layer_params(p, li)
-                xn = apply_norm(x, blk["ln1"], cfg.norm)
-                q = (xn @ blk["attn"]["wq"]).reshape(B, 1, cfg.n_heads, cfg.hd)
-                k = (xn @ blk["attn"]["wk"]).reshape(B, 1, cfg.n_kv_heads, cfg.hd)
-                v = (xn @ blk["attn"]["wv"]).reshape(B, 1, cfg.n_kv_heads, cfg.hd)
-                if "bq" in blk["attn"]:
-                    q = q + blk["attn"]["bq"].reshape(1, 1, cfg.n_heads, cfg.hd)
-                    k = k + blk["attn"]["bk"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
-                    v = v + blk["attn"]["bv"].reshape(1, 1, cfg.n_kv_heads, cfg.hd)
-                if cfg.pos == "rope":
-                    q = rope(q, pos, cfg.rope_theta)
-                    k = rope(k, pos, cfg.rope_theta)
+            for li, lid in enumerate(self._layer_ids):
+                q, ks, vs = self._attn_in(blocks, lid, x, pos)
                 for bi in range(B):
-                    new_kv[bi][li] = (k[bi, 0], v[bi, 0])
+                    new_kv[bi][li] = (ks[bi], vs[bi])
                 # append THIS layer's kv before attending (token attends to self)
                 with self.cache.metrics.span("kv.append"):
                     if li == 0:
@@ -150,15 +197,11 @@ class PagedLM:
                         # layers >0: write into the already-appended slot
                         for bi, sid in enumerate(sids):
                             self._overwrite_token(sid, li, new_kv[bi][li])
-                a = self.cache.attention(li, q[:, 0], sids,
+                a = self.cache.attention(li, q, sids,
                                          use_kernel=self.use_kernel)
-                x = x + a.reshape(B, 1, -1) @ blk["attn"]["wo"]
-                h = apply_norm(x, blk["ln2"], cfg.norm)
-                from repro.models.layers import mlp_apply
-                x = x + mlp_apply(h, blk["mlp"], cfg.act)
-            x = apply_norm(x, p["final_norm"], cfg.norm)
-            w = p["embed"].T if cfg.tie_embeddings else p["head"]
-            return (x @ w).astype(jnp.float32)[:, 0]
+                x = self._attn_out(blocks, lid, x, a)
+            w = p["embed"] if cfg.tie_embeddings else p["head"]
+            return self._head(p["final_norm"], w, x)
 
     def _overwrite_token(self, sid: int, layer: int, kv) -> None:
         # delegated: the cache serializes the pool/table write on _tlock
