@@ -220,12 +220,20 @@ SERVE_SPANS = (
     "vol.write_multi",  # volume worker: one chained multi-block record write
 )
 
-# Serving-path counter beside the spans, on the same shared Metrics:
-#   lm.dense_traces — traces of PagedLM's jitted dense math (embedding,
-#                     attention input, attention output, head), bumped in
-#                     each function's body, which runs only while JAX
-#                     traces: four per batch shape over a run, never one
-#                     per layer or per step
+# Serving-path counters beside the spans, on the same shared Metrics:
+#   lm.dense_traces     — traces of PagedLM's jitted dense math (embedding,
+#                         attention input, attention output, head), bumped
+#                         in each function's body, which runs only while
+#                         JAX traces: four per batch shape over a run,
+#                         never one per layer or per step
+#   kv.write_traces     — traces of PagedKVCache's jitted slot writer,
+#                         bumped in its body: one per writer shape (prefill's
+#                         every-layer, one-slot write; decode's one-layer,
+#                         B-slot write per batch size), never one per call
+#   kv_slot_writes      — slots written, N per writer call (a prefill token
+#                         is 1 slot across every layer, a decode layer B)
+#   kv_host_slot_writes — of those, slots on a host-fresh page, written
+#                         through numpy instead of the scatter
 
 
 #: EWMA smoothing for :meth:`Metrics.observe` — ~the last 10-ish

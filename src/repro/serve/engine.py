@@ -18,9 +18,11 @@ Scheduling follows the paper's transit discipline:
     shape changes.
 
 The decode step's layer loop runs in Python over per-layer pools: each
-layer's dense math is two jitted calls around the cache's appends and
-attention.  Prefill is eager ops.  The same code serves the CPU tests
-(smoke widths) and the chip (full widths, ``launch/serve.py --full``).
+layer's dense math is two jitted calls around the cache's slot write
+(one jitted in-place scatter for the batch) and attention.  Prefill is
+eager ops; it writes each token's K/V for every layer in one scatter.
+The same code serves the CPU tests (smoke widths) and the chip (full
+widths, ``launch/serve.py --full``).
 """
 from __future__ import annotations
 
@@ -72,8 +74,8 @@ def _dense_step_fns(cfg: ModelConfig, bump):
         return jnp.take(table, tokens[:, None], axis=0)      # (B, 1, D)
 
     def attn_in(blocks, li, x, pos):
-        """ln1, QKV (+bias), RoPE -> q (B, H, hd) and B per-sequence
-        (Hkv, hd) keys and values, as the cache's appends take them."""
+        """ln1, QKV (+bias), RoPE -> q (B, H, hd), and the keys and
+        values (B, Hkv, hd) the cache writes."""
         bump("lm.dense_traces")
         blk = jax.tree.map(lambda a: a[li], blocks)
         B = x.shape[0]
@@ -88,8 +90,7 @@ def _dense_step_fns(cfg: ModelConfig, bump):
         if cfg.pos == "rope":
             q = rope(q, pos[:, None], cfg.rope_theta)
             k = rope(k, pos[:, None], cfg.rope_theta)
-        return (q[:, 0], tuple(k[bi, 0] for bi in range(B)),
-                tuple(v[bi, 0] for bi in range(B)))
+        return q[:, 0], k[:, 0], v[:, 0]
 
     def attn_out(blocks, li, x, a):
         """wo and the residual, ln2, the MLP and its residual."""
@@ -157,12 +158,11 @@ class PagedLM:
                 h = apply_norm(x, blk["ln2"], cfg.norm)
                 x = x + mlp_apply(h, blk["mlp"], cfg.act)
                 kv_per_layer.append((k[0], v[0]))            # (T, Hkv, hd)
-            # append pages token-by-token (bulk write path)
+            # append pages token by token, each token's rows of every
+            # layer from one copy of the prompt's K/V on the host
+            ks, vs = (np.asarray(jnp.stack(a)) for a in zip(*kv_per_layer))
             for t in range(T):
-                self.cache.append_token(
-                    sid,
-                    [kv_per_layer[li][0][t] for li in range(cfg.n_layers)],
-                    [kv_per_layer[li][1][t] for li in range(cfg.n_layers)])
+                self.cache.append_token(sid, list(ks[:, t]), list(vs[:, t]))
             x = apply_norm(x[:, -1:], p["final_norm"], cfg.norm)
             w = p["embed"].T if cfg.tie_embeddings else p["head"]
             return (x @ w).astype(jnp.float32)[0, 0]
@@ -173,40 +173,23 @@ class PagedLM:
         (B, V) logits."""
         with self.cache.metrics.span("lm.decode"):
             cfg, p = self.cfg, self.params
-            B = len(tokens)
             blocks = p["blocks"]
             x = self._embed(p["embed"], jnp.asarray(tokens, jnp.int32))
             pos = jnp.asarray(positions, jnp.int32)
-            new_kv = [[None] * cfg.n_layers for _ in range(B)]
+            slots = None
             for li, lid in enumerate(self._layer_ids):
-                q, ks, vs = self._attn_in(blocks, lid, x, pos)
-                for bi in range(B):
-                    new_kv[bi][li] = (ks[bi], vs[bi])
-                # append THIS layer's kv before attending (token attends to self)
+                q, k, v = self._attn_in(blocks, lid, x, pos)
+                # write THIS layer's kv before attending (token attends to
+                # self); layer 0 reserves the slot every layer writes
                 with self.cache.metrics.span("kv.append"):
-                    if li == 0:
-                        for bi, sid in enumerate(sids):
-                            self.cache.append_token(
-                                sid, [new_kv[bi][L][0] if new_kv[bi][L] else
-                                      jnp.zeros((cfg.n_kv_heads, cfg.hd), cfg.dtype)
-                                      for L in range(cfg.n_layers)],
-                                [new_kv[bi][L][1] if new_kv[bi][L] else
-                                 jnp.zeros((cfg.n_kv_heads, cfg.hd), cfg.dtype)
-                                 for L in range(cfg.n_layers)])
-                    else:
-                        # layers >0: write into the already-appended slot
-                        for bi, sid in enumerate(sids):
-                            self._overwrite_token(sid, li, new_kv[bi][li])
+                    if slots is None:
+                        slots = self.cache.reserve_slots(sids)
+                    self.cache.write_slots(slots, range(li, li + 1), k, v)
                 a = self.cache.attention(li, q, sids,
                                          use_kernel=self.use_kernel)
                 x = self._attn_out(blocks, lid, x, a)
             w = p["embed"] if cfg.tie_embeddings else p["head"]
             return self._head(p["final_norm"], w, x)
-
-    def _overwrite_token(self, sid: int, layer: int, kv) -> None:
-        # delegated: the cache serializes the pool/table write on _tlock
-        # (an unlocked write here would race the eviction-pool workers)
-        self.cache.overwrite_token(sid, layer, kv)
 
 
 class AsyncRequestLog:

@@ -38,17 +38,26 @@ decode attention resolves the table inside the Pallas kernel (Mosaic); on
 the CPU the jnp ref, or the same kernel in interpret mode, does the same
 resolution.
 
+The write path is one jitted scatter that donates the pools: a decode
+step reserves each sequence's slot once (``reserve_slots``), then writes
+one layer's K/V for all of them per call (``write_slots``); a prefill
+token writes every layer's in one call (``append_token``).
+
 Concurrency contract: ``seq.table``, ``self._free``, the host tier and the
 active flags are guarded by ``_tlock`` — public entry points take it,
 ``_locked`` helpers assume it (the eviction-pool workers' ``_evict_slot*``
 hooks take the same lock, so a decode-thread ``append_token`` can never
-interleave with a worker's page-out on the same free list).
+interleave with a worker's page-out on the same free list).  A pool
+array is read and replaced only under ``_tlock`` or on the decode thread
+that owns the writes: a write donates the array it replaces, so nothing
+may hold a pool array across a write.
 """
 from __future__ import annotations
 
 import threading
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -118,6 +127,38 @@ class Sequence:
     active: bool = True
 
 
+@dataclass
+class Slots:
+    """One token's reserved slot in each of N sequences.  ``pages`` and
+    ``offs`` are (N,) int32 indices into the pools; a host-resident slot
+    carries the out-of-range page ``n_pages``, which the scatter drops,
+    and is listed in ``host`` as (row, host-fresh buffer, offset)."""
+    pages: object
+    offs: object
+    host: list
+
+
+def _scatter_slots(bump, k_pools, v_pools, pages, offs, k, v):
+    """Write N slots into each pool of ``k_pools``/``v_pools``: k/v hold
+    (n_pools, N, Hkv, hd) rows in any shape of that size, cast to the
+    pool dtype (the convert an eager ``.astype`` makes).  The body runs
+    only while JAX traces, and bumps ``kv.write_traces`` then."""
+    bump("kv.write_traces")
+    shape = (len(k_pools), pages.shape[0]) + k_pools[0].shape[2:]
+    k, v = k.reshape(shape), v.reshape(shape)
+
+    def put(pools, rows):
+        return tuple(p.at[pages, offs].set(r.astype(p.dtype), mode="drop")
+                     for p, r in zip(pools, rows))
+    return put(k_pools, k), put(v_pools, v)
+
+
+# the pools are donated: XLA updates them in place, and the arrays passed
+# in are dead once the call returns
+_write_slots = jax.jit(_scatter_slots, static_argnums=0,
+                       donate_argnums=(1, 2))
+
+
 class PagedKVCache:
     """Host-side manager + on-device pools for one model's KV state."""
 
@@ -128,9 +169,10 @@ class PagedKVCache:
         self.metrics = metrics or Metrics()
         # optional SharedEvictionPool: eager page-out DMA runs on the
         # volume's eviction cores instead of the decode thread (the
-        # paper's per-device eviction threads, shared).  jnp pools are
-        # immutable so workers gather from a consistent snapshot; table /
-        # free-list / host-tier mutations serialize on _tlock.
+        # paper's per-device eviction threads, shared).  Workers gather
+        # from the pools under _tlock, which every pool write holds (a
+        # write donates the array it replaces); table / free-list /
+        # host-tier mutations serialize on it too.
         self._tlock = threading.Lock()
         self._evict_cv = threading.Condition(self._tlock)
         self._evict_pool = evict_pool
@@ -187,11 +229,16 @@ class PagedKVCache:
         return False
 
     # -------------------------------------------------------------- write path
-    def append_token(self, sid: int, k_token, v_token) -> None:
-        """k/v_token: per-layer list of (Hkv, hd) arrays for ONE new token."""
-        with self._tlock:
+    def _reserve_locked(self, sids: list[int]) -> Slots:
+        """Reserve the next token slot of each sequence in ``sids``,
+        allocating a fresh page at a page boundary (or bypassing it to
+        the host tier), and count the token in ``seq.length``."""
+        pg = self.cfg.page_size
+        pages = np.full((len(sids),), self.cfg.n_pages, np.int32)
+        offs = np.zeros((len(sids),), np.int32)
+        host = []
+        for row, sid in enumerate(sids):
             seq = self.seqs[sid]
-            pg = self.cfg.page_size
             off = seq.length % pg
             if off == 0:                                 # need a fresh page
                 # max_pages_per_seq bounds the DENSE block table the fast
@@ -224,39 +271,54 @@ class PagedKVCache:
                 else:
                     seq.table.append(("hbm", page))
             entry = seq.table[seq.length // pg]
+            offs[row] = off
             if entry[0] == "hbm":
-                page = entry[1]
-                for li in range(self.cfg.n_layers):
-                    self.k_pool[li] = self.k_pool[li].at[page, off].set(
-                        k_token[li].astype(self.cfg.dtype))
-                    self.v_pool[li] = self.v_pool[li].at[page, off].set(
-                        v_token[li].astype(self.cfg.dtype))
+                pages[row] = entry[1]
             else:                                        # host-resident page
-                buf = entry[1]
-                for li in range(self.cfg.n_layers):
-                    buf["k"][li][off] = np.asarray(k_token[li], np.float32)
-                    buf["v"][li][off] = np.asarray(v_token[li], np.float32)
+                host.append((row, entry[1], off))
             seq.length += 1
+        return Slots(pages, offs, host)
 
-    def overwrite_token(self, sid: int, layer: int, kv) -> None:
-        """Rewrite the LAST appended token's k/v for one layer (the decode
-        loop appends at layer 0, then fills layers > 0 in place)."""
+    def _write_locked(self, slots: Slots, layers: range, k, v) -> None:
+        """Write one token's K/V into ``slots`` for the pools of
+        ``layers``: the HBM rows in one in-place scatter, the host rows
+        through numpy."""
+        n = len(slots.offs)
+        self.metrics.bump("kv_slot_writes", n)
+        lo, hi = layers.start, layers.stop
+        self.k_pool[lo:hi], self.v_pool[lo:hi] = _write_slots(
+            self.metrics.bump, tuple(self.k_pool[lo:hi]),
+            tuple(self.v_pool[lo:hi]), slots.pages, slots.offs, k, v)
+        if slots.host:
+            shape = (hi - lo, n, self.cfg.n_kv_heads, self.cfg.head_dim)
+            k_rows = np.asarray(k, np.float32).reshape(shape)
+            v_rows = np.asarray(v, np.float32).reshape(shape)
+            for row, buf, off in slots.host:
+                buf["k"][lo:hi, off] = k_rows[:, row]
+                buf["v"][lo:hi, off] = v_rows[:, row]
+                self.metrics.bump("kv_host_slot_writes")
+
+    def reserve_slots(self, sids: list[int]) -> Slots:
+        """Reserve one token slot per sequence for every layer; the
+        index arrays go to the device once, for all the layers' writes."""
         with self._tlock:
-            seq = self.seqs[sid]
-            pgsz = self.cfg.page_size
-            tpos = seq.length - 1
-            entry = seq.table[tpos // pgsz]
-            off = tpos % pgsz
-            k_t, v_t = kv
-            if entry[0] == "hbm":
-                page = entry[1]
-                self.k_pool[layer] = self.k_pool[layer].at[page, off].set(
-                    k_t.astype(self.cfg.dtype))
-                self.v_pool[layer] = self.v_pool[layer].at[page, off].set(
-                    v_t.astype(self.cfg.dtype))
-            else:
-                entry[1]["k"][layer][off] = np.asarray(k_t, np.float32)
-                entry[1]["v"][layer][off] = np.asarray(v_t, np.float32)
+            slots = self._reserve_locked(sids)
+        return Slots(jnp.asarray(slots.pages), jnp.asarray(slots.offs),
+                     slots.host)
+
+    def write_slots(self, slots: Slots, layers: range, k, v) -> None:
+        """k/v: the token's rows for ``layers`` x the slots' sequences,
+        ``(len(layers), N, Hkv, hd)`` or any shape of that size."""
+        with self._tlock:
+            self._write_locked(slots, layers, k, v)
+
+    def append_token(self, sid: int, k_token, v_token) -> None:
+        """k/v_token: per-layer list of (Hkv, hd) arrays for ONE new token."""
+        k = np.stack([np.asarray(x) for x in k_token])
+        v = np.stack([np.asarray(x) for x in v_token])
+        with self._tlock:
+            self._write_locked(self._reserve_locked([sid]),
+                               range(self.cfg.n_layers), k, v)
 
     def _host_fresh_page(self) -> dict:
         L, pg, H, hd = (self.cfg.n_layers, self.cfg.page_size,

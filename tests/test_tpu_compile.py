@@ -73,3 +73,19 @@ def test_flash_attention_compiles_for_v5e(sds):
     kv = sds((1, 512, HKV, HD), jnp.bfloat16)
     _compile(flash_attention_pallas, sds((1, 512, H, HD), jnp.bfloat16),
              kv, kv)
+
+
+@pytest.mark.parametrize("n_pools,n_slots", [(36, 1), (1, B)])
+def test_kv_slot_writer_compiles_in_place_for_v5e(sds, n_pools, n_slots):
+    """The KV slot writer at prefill's shape (every layer, one slot) and
+    decode's (one layer, B slots): the donated pools alias its outputs,
+    so the chip updates them in place rather than copying them."""
+    from repro.serve.kvcache import _write_slots
+    pools = tuple(sds((PAGES, PAGE, HKV, HD), jnp.bfloat16)
+                  for _ in range(n_pools))
+    rows = sds((n_pools, n_slots, HKV, HD), jnp.bfloat16)
+    idx = sds((n_slots,), jnp.int32)
+    compiled = _write_slots.lower(lambda name: None, pools, pools, idx, idx,
+                                  rows, rows).compile()
+    pool_bytes = 2 * n_pools * PAGES * PAGE * HKV * HD * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
