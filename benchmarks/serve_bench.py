@@ -4,8 +4,9 @@ cache (real engine, smoke model, CPU wall time — relative numbers).
 Scenario: more concurrent requests than the HBM pool can hold.
   * transit (eager page-out of retired/preempted sequences + bypass):
     decode keeps running; finished sequences vacate pages immediately.
-  * staging (no eager page-out, no bypass): admission stalls on a full
-    pool — the serving analogue of the paper's staging-cache stalls.
+  * staging (no bypass): admission stalls on a full pool — the serving
+    analogue of the paper's staging-cache stalls.  Both modes page out
+    eagerly: the cache has no switch for it.
 """
 from __future__ import annotations
 
@@ -32,7 +33,6 @@ def run(n_requests: int = 10, prompt_len: int = 24, max_new: int = 8,
             n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.hd, page_size=page_size, n_pages=pool_pages,
             max_pages_per_seq=(prompt_len + max_new) // page_size + 2,
-            eager_eviction=(mode == "transit"),
             conditional_bypass=(mode == "transit"))
         eng = ServeEngine(cfg, params, cache_cfg=cache_cfg, max_batch=3)
         rng = np.random.default_rng(0)

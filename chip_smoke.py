@@ -98,12 +98,17 @@ def kernel_phase(*, seed: int = 0, pool_pages: int = 256, page: int = 16,
             _check(int(c) == zlib.adler32(qn[i].tobytes()),
                    f"Adler-32 of page {i} (n={n}) != zlib.adler32")
 
-        base = jnp.zeros_like(pool)
-        (restored, rcrc), c_scatter, s_scatter = _timed(
-            ops.scatter_dequantize_crc, base, ids, q, sc)
+        restored = [jnp.zeros_like(pool)]
+
+        def restore(*args):
+            # the codec donates its pool: each call restores into the
+            # pool the call before returned
+            restored[0], rcrc = ops.scatter_dequantize_crc(restored[0], *args)
+            return rcrc
+        rcrc, c_scatter, s_scatter = _timed(restore, ids, q, sc)
         _check(np.array_equal(np.asarray(rcrc), np.asarray(crc)),
                f"restore checksum != spill checksum (n={n})")
-        got = np.asarray(restored, np.float32)
+        got = np.asarray(restored[0], np.float32)
         orig = np.asarray(pool, np.float32)
         idn = np.asarray(ids)
         step = np.abs(orig[idn]).max(axis=-1, keepdims=True) / 127.0
@@ -120,13 +125,13 @@ def kernel_phase(*, seed: int = 0, pool_pages: int = 256, page: int = 16,
     out["mosaic"]["gather_quantize_crc"] = _mosaic(
         ops.gather_quantize_crc, pool, ids)
     out["mosaic"]["scatter_dequantize_crc"] = _mosaic(
-        ops.scatter_dequantize_crc, base, ids, q, sc)
+        ops.scatter_dequantize_crc, restored[0], ids, q, sc)
 
     qa = jnp.asarray(rng.standard_normal((batch, heads, head_dim)), dtype)
     kp = jnp.asarray(rng.standard_normal(
-        (pool_pages, page, kv_heads, head_dim)), dtype)
+        (pool_pages, page, kv_heads * head_dim)), dtype)
     vp = jnp.asarray(rng.standard_normal(
-        (pool_pages, page, kv_heads, head_dim)), dtype)
+        (pool_pages, page, kv_heads * head_dim)), dtype)
     table = jnp.asarray(rng.permutation(pool_pages)[:batch * max_pages]
                         .reshape(batch, max_pages), jnp.int32)
     lens = rng.integers(1, page * max_pages + 1, (batch,))

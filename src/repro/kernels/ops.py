@@ -56,9 +56,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 @jax.jit
-def paged_attention(q, k_pool, v_pool, block_table, seq_lens):
+def paged_attention(q, k_pool, v_pool, block_table, seq_lens, row_base=0):
+    """Paged decode attention over row pools; ``row_base`` (traced) is
+    where the table's page 0 starts, so one executable serves every
+    layer of a pool that stacks them."""
     return paged_attention_pallas(q, k_pool, v_pool, block_table, seq_lens,
-                                  interpret=not on_tpu())
+                                  row_base, interpret=not on_tpu())
 
 
 @jax.jit
@@ -70,22 +73,11 @@ def gather_quantize_crc(pool, page_ids):
                                       interpret=not on_tpu())
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=0)
 def scatter_dequantize_crc(pool, page_ids, q, scales):
     """Fused restore codec: dequantize+scatter plus the checksum of the
-    payload as received, for the caller to verify against spill time."""
+    payload as received, for the caller to verify against spill time.
+    The pool is donated: the kernel writes its pages in place, and the
+    array passed in is dead once the call returns."""
     return scatter_dequantize_crc_pallas(pool, page_ids, q, scales,
                                          interpret=not on_tpu())
-
-
-@jax.jit
-def gather_quantize(pool, page_ids):
-    """The spill codec without its checksum (same kernel)."""
-    q, scales, _ = gather_quantize_crc(pool, page_ids)
-    return q, scales
-
-
-@jax.jit
-def scatter_dequantize(pool, page_ids, q, scales):
-    """The restore codec without its checksum (same kernel)."""
-    return scatter_dequantize_crc(pool, page_ids, q, scales)[0]

@@ -86,19 +86,18 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def paged_attention_pallas(q, k_pool, v_pool, block_table, seq_lens, *,
-                           interpret: bool = False):
-    """q: (B, H, hd);  pools: (P, page_size, Hkv, hd);
-    block_table: (B, max_pages) int32;  seq_lens: (B,) int32
-    -> (B, H, hd)."""
+def paged_attention_pallas(q, k_pool, v_pool, block_table, seq_lens,
+                           row_base=0, *, interpret: bool = False):
+    """q: (B, H, hd);  pools: (N, page_size, Hkv*hd) rows;
+    block_table: (B, max_pages) int32, page p at row block
+    ``row_base + p``;  seq_lens: (B,) int32 -> (B, H, hd)."""
     B, H, hd = q.shape
-    P, page_size, Hkv, _ = k_pool.shape
+    _, page_size, F = k_pool.shape
+    Hkv = F // hd
     max_pages = block_table.shape[1]
     n_rep = H // Hkv
     scale = 1.0 / math.sqrt(hd)
-    kp = k_pool.reshape(P, page_size, Hkv * hd)     # free: pages contiguous
-    vp = v_pool.reshape(P, page_size, Hkv * hd)
-    table = block_table.astype(jnp.int32).reshape(-1)
+    table = (block_table.astype(jnp.int32) + row_base).reshape(-1)
 
     def page_map(b, p, table_ref, lens_ref):
         # pages past the length re-map to the last valid one: same block
@@ -132,5 +131,5 @@ def paged_attention_pallas(q, k_pool, v_pool, block_table, seq_lens, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(table, seq_lens.astype(jnp.int32), q.reshape(B, Hkv, n_rep, hd),
-      kp, vp)
+      k_pool, v_pool)
     return out.reshape(B, H, hd)

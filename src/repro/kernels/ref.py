@@ -31,18 +31,22 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
     return out.astype(q.dtype)
 
 
-def paged_attention_ref(q, k_pool, v_pool, block_table, seq_lens):
-    """q: (B, H, hd); pools: (P, page, Hkv, hd); block_table: (B, max_pages);
-    seq_lens: (B,) -> (B, H, hd)."""
+def paged_attention_ref(q, k_pool, v_pool, block_table, seq_lens,
+                        row_base=0):
+    """q: (B, H, hd); pools: (N, page, Hkv*hd) rows; block_table:
+    (B, max_pages), page p at row block ``row_base + p``; seq_lens: (B,)
+    -> (B, H, hd)."""
     B, H, hd = q.shape
-    P, page, Hkv, _ = k_pool.shape
+    _, page, F = k_pool.shape
+    Hkv = F // hd
     n_rep = H // Hkv
     max_pages = block_table.shape[1]
     scale = 1.0 / math.sqrt(hd)
 
     # materialize (B, max_pages*page, Hkv, hd) views via the table
-    k = k_pool[block_table].reshape(B, max_pages * page, Hkv, hd)
-    v = v_pool[block_table].reshape(B, max_pages * page, Hkv, hd)
+    rows = block_table + row_base
+    k = k_pool[rows].reshape(B, max_pages * page, Hkv, hd)
+    v = v_pool[rows].reshape(B, max_pages * page, Hkv, hd)
     k = jnp.repeat(k, n_rep, axis=2).astype(jnp.float32)
     v = jnp.repeat(v, n_rep, axis=2).astype(jnp.float32)
     s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32), k) * scale

@@ -17,10 +17,12 @@ Scheduling follows the paper's transit discipline:
   * a step "fsync" (``barrier``) completes all migrations before the batch
     shape changes.
 
-The decode step's layer loop runs in Python over per-layer pools: each
+The decode step's layer loop runs in Python over the cache's two pools,
+one array for K and one for V, each layer a block of their rows: each
 layer's dense math is two jitted calls around the cache's slot write
-(one jitted in-place scatter for the batch) and attention.  Prefill is
-eager ops; it writes each token's K/V for every layer in one scatter.
+(one jitted in-place scatter for the batch) and attention, both of which
+take the layer as a traced row offset.  Prefill is eager ops; it writes
+each token's K/V for every layer in one scatter.
 The same code serves the CPU tests (smoke widths) and the chip (full
 widths, ``launch/serve.py --full``).
 """

@@ -8,13 +8,13 @@ Mapping of the paper's structures:
   DRAM transit cache          -> the HBM pool itself is the *fast* tier;
                                  the host tier (int8-packed) is the slow one
   eager eviction              -> cold sequences' pages are packed
-                                 (gather_quantize) to the host tier as soon
-                                 as the sequence stops decoding
+                                 (gather_quantize_crc) to the host tier as
+                                 soon as the sequence stops decoding
   conditional bypass          -> a page allocation against a full pool goes
                                  straight to the host tier (no stall evicting
                                  someone else's hot page on the decode path)
-  fsync / PREFLUSH            -> ``barrier()``: complete all pending
-                                 migrations (used before pool reshape)
+  fsync / PREFLUSH            -> ``drain_evictions()``: complete all
+                                 pending page-outs before a resume
   volume read tier            -> a small CLOCK cache of *dequantized* host
                                  pages (``repro.volume.ReadTier`` in object
                                  mode): hybrid attention re-reads the same
@@ -33,15 +33,23 @@ Mapping of the paper's structures:
                                  (int8) -> volume, exactly the paper's
                                  transit-cache -> PMem descent.
 
-The pool arrays live per layer: (P, page_size, Hkv, hd).  On a TPU the
-decode attention resolves the table inside the Pallas kernel (Mosaic); on
-the CPU the jnp ref, or the same kernel in interpret mode, does the same
-resolution.
+K and V are one device array each, of shape (L*P, page_size, Hkv*hd):
+the rows both Pallas kernels read, so no call reshapes a pool.  Physical
+page p of layer li is row block li*P + p.  The free list, the block
+tables and ``occupancy`` count pages 0..P-1, and a free page is free in
+every layer.  A call takes a layer as its row offset li*P, an int32 on
+the device that the jitted callee traces, so one executable serves every
+layer.  On a TPU the decode attention resolves the table inside the
+Pallas kernel (Mosaic); on the CPU the jnp ref, or the same kernel in
+interpret mode, does the same resolution.
 
 The write path is one jitted scatter that donates the pools: a decode
 step reserves each sequence's slot once (``reserve_slots``), then writes
 one layer's K/V for all of them per call (``write_slots``); a prefill
-token writes every layer's in one call (``append_token``).
+token writes every layer's in one call (``append_token``), as does the
+page-in of a bypassed page.  A slot on a host-resident page carries the
+page L*P, whose row li*P + L*P lies past the pool in every layer, so the
+scatter drops it.  The restore codec donates its pool as well.
 
 Concurrency contract: ``seq.table``, ``self._free``, the host tier and the
 active flags are guarded by ``_tlock`` — public entry points take it,
@@ -49,8 +57,8 @@ active flags are guarded by ``_tlock`` — public entry points take it,
 hooks take the same lock, so a decode-thread ``append_token`` can never
 interleave with a worker's page-out on the same free list).  A pool
 array is read and replaced only under ``_tlock`` or on the decode thread
-that owns the writes: a write donates the array it replaces, so nothing
-may hold a pool array across a write.
+that owns the writes: a write or a restore donates the array it replaces,
+so nothing may hold a pool array across either.
 """
 from __future__ import annotations
 
@@ -79,7 +87,6 @@ class PagedCacheConfig:
                                   # when a KVPager is attached)
     max_pages_per_seq: int = 64
     dtype: object = jnp.bfloat16
-    eager_eviction: bool = True
     conditional_bypass: bool = True
     read_tier_pages: int = 128    # dequantized-page cache (0 disables)
 
@@ -130,27 +137,29 @@ class Sequence:
 @dataclass
 class Slots:
     """One token's reserved slot in each of N sequences.  ``pages`` and
-    ``offs`` are (N,) int32 indices into the pools; a host-resident slot
-    carries the out-of-range page ``n_pages``, which the scatter drops,
-    and is listed in ``host`` as (row, host-fresh buffer, offset)."""
+    ``offs`` are (N,) int32: a page counts 0..P-1, and the write adds a
+    layer's row offset.  A host-resident slot carries the page L*P, past
+    the pool in every layer, which the scatter drops, and is listed in
+    ``host`` as (row, host-fresh buffer, offset)."""
     pages: object
     offs: object
     host: list
 
 
-def _scatter_slots(bump, k_pools, v_pools, pages, offs, k, v):
-    """Write N slots into each pool of ``k_pools``/``v_pools``: k/v hold
-    (n_pools, N, Hkv, hd) rows in any shape of that size, cast to the
-    pool dtype (the convert an eager ``.astype`` makes).  The body runs
-    only while JAX traces, and bumps ``kv.write_traces`` then."""
+def _scatter_slots(bump, k_pool, v_pool, bases, pages, offs, k, v):
+    """Write N slots in each layer whose row offset is in ``bases``: row
+    ``bases[i] + pages[n]``, offset ``offs[n]``; a row past the pool is
+    dropped.  k/v hold (len(bases), N, Hkv*hd) values in any shape of
+    that size, cast to the pool dtype (the convert an eager ``.astype``
+    makes).  The body runs only while JAX traces, and bumps
+    ``kv.write_traces`` then."""
     bump("kv.write_traces")
-    shape = (len(k_pools), pages.shape[0]) + k_pools[0].shape[2:]
-    k, v = k.reshape(shape), v.reshape(shape)
+    rows = bases[:, None] + pages[None, :]
 
-    def put(pools, rows):
-        return tuple(p.at[pages, offs].set(r.astype(p.dtype), mode="drop")
-                     for p, r in zip(pools, rows))
-    return put(k_pools, k), put(v_pools, v)
+    def put(pool, x):
+        x = x.reshape(rows.shape + pool.shape[2:]).astype(pool.dtype)
+        return pool.at[rows, offs].set(x, mode="drop")
+    return put(k_pool, k), put(v_pool, v)
 
 
 # the pools are donated: XLA updates them in place, and the arrays passed
@@ -185,11 +194,18 @@ class PagedKVCache:
         if pager is not None and getattr(pager, "own_metrics", False):
             pager.metrics = self.metrics     # unify the kv_* counters
             pager.own_metrics = False
-        L, P, pg, H, hd = (cfg.n_layers, cfg.n_pages, cfg.page_size,
-                          cfg.n_kv_heads, cfg.head_dim)
-        self.k_pool = [jnp.zeros((P, pg, H, hd), cfg.dtype) for _ in range(L)]
-        self.v_pool = [jnp.zeros((P, pg, H, hd), cfg.dtype) for _ in range(L)]
-        self._free: list[int] = list(range(P))          # global free set
+        L, P = cfg.n_layers, cfg.n_pages
+        rows = (L * P, cfg.page_size, cfg.n_kv_heads * cfg.head_dim)
+        self.k_pool = jnp.zeros(rows, cfg.dtype)
+        self.v_pool = jnp.zeros(rows, cfg.dtype)
+        # row offsets li*P, put on the device once for every layer and
+        # for each one alone: a write or an attention call names its
+        # layers by them, so no call sends an offset of its own
+        base = np.arange(L, dtype=np.int32) * P
+        self._bases = {range(li, li + 1): jnp.asarray(base[li:li + 1])
+                       for li in range(L)}
+        self._bases[range(L)] = jnp.asarray(base)
+        self._free: list[int] = list(range(P))   # free in every layer
         self.host = HostTier()
         # clean read tier over the host tier: caches dequantized pages for
         # the hybrid-attention slow path (object mode — slots hold arrays)
@@ -234,7 +250,7 @@ class PagedKVCache:
         allocating a fresh page at a page boundary (or bypassing it to
         the host tier), and count the token in ``seq.length``."""
         pg = self.cfg.page_size
-        pages = np.full((len(sids),), self.cfg.n_pages, np.int32)
+        pages = np.full((len(sids),), self.k_pool.shape[0], np.int32)
         offs = np.zeros((len(sids),), np.int32)
         host = []
         for row, sid in enumerate(sids):
@@ -280,17 +296,17 @@ class PagedKVCache:
         return Slots(pages, offs, host)
 
     def _write_locked(self, slots: Slots, layers: range, k, v) -> None:
-        """Write one token's K/V into ``slots`` for the pools of
-        ``layers``: the HBM rows in one in-place scatter, the host rows
-        through numpy."""
+        """Write one token's K/V into ``slots`` for ``layers`` (every
+        layer, or one): the HBM rows in one in-place scatter, the host
+        rows through numpy."""
         n = len(slots.offs)
         self.metrics.bump("kv_slot_writes", n)
-        lo, hi = layers.start, layers.stop
-        self.k_pool[lo:hi], self.v_pool[lo:hi] = _write_slots(
-            self.metrics.bump, tuple(self.k_pool[lo:hi]),
-            tuple(self.v_pool[lo:hi]), slots.pages, slots.offs, k, v)
+        self.k_pool, self.v_pool = _write_slots(
+            self.metrics.bump, self.k_pool, self.v_pool,
+            self._bases[layers], slots.pages, slots.offs, k, v)
         if slots.host:
-            shape = (hi - lo, n, self.cfg.n_kv_heads, self.cfg.head_dim)
+            lo, hi = layers.start, layers.stop
+            shape = (hi - lo, n, self.cfg.n_kv_heads * self.cfg.head_dim)
             k_rows = np.asarray(k, np.float32).reshape(shape)
             v_rows = np.asarray(v, np.float32).reshape(shape)
             for row, buf, off in slots.host:
@@ -307,8 +323,9 @@ class PagedKVCache:
                      slots.host)
 
     def write_slots(self, slots: Slots, layers: range, k, v) -> None:
-        """k/v: the token's rows for ``layers`` x the slots' sequences,
-        ``(len(layers), N, Hkv, hd)`` or any shape of that size."""
+        """k/v: the token's rows for ``layers`` (every layer, or one) x
+        the slots' sequences, ``(len(layers), N, Hkv*hd)`` or any shape
+        of that size."""
         with self._tlock:
             self._write_locked(slots, layers, k, v)
 
@@ -321,10 +338,10 @@ class PagedKVCache:
                                range(self.cfg.n_layers), k, v)
 
     def _host_fresh_page(self) -> dict:
-        L, pg, H, hd = (self.cfg.n_layers, self.cfg.page_size,
-                        self.cfg.n_kv_heads, self.cfg.head_dim)
-        return {"k": np.zeros((L, pg, H, hd), np.float32),
-                "v": np.zeros((L, pg, H, hd), np.float32)}
+        shape = (self.cfg.n_layers, self.cfg.page_size,
+                 self.cfg.n_kv_heads * self.cfg.head_dim)
+        return {"k": np.zeros(shape, np.float32),
+                "v": np.zeros(shape, np.float32)}
 
     # ----------------------------------------------------------- transit ops
     def _page_out_locked(self, seq: Sequence, logical: int) -> None:
@@ -336,14 +353,10 @@ class PagedKVCache:
             kind, page = seq.table[logical]
             assert kind == "hbm"
             handles = []
-            ids = jnp.array([page], jnp.int32)
             for li in range(self.cfg.n_layers):
-                pool_k = self.k_pool[li].reshape(self.cfg.n_pages,
-                                                 self.cfg.page_size, -1)
-                pool_v = self.v_pool[li].reshape(self.cfg.n_pages,
-                                                 self.cfg.page_size, -1)
-                qk, sk, ck = gather_quantize_crc(pool_k, ids)
-                qv, sv, cv = gather_quantize_crc(pool_v, ids)
+                ids = jnp.asarray([li * self.cfg.n_pages + page], jnp.int32)
+                qk, sk, ck = gather_quantize_crc(self.k_pool, ids)
+                qv, sv, cv = gather_quantize_crc(self.v_pool, ids)
                 hk = self.host.put(li, np.asarray(qk[0]), np.asarray(sk[0]),
                                    int(ck[0]))
                 hv = self.host.put(li, np.asarray(qv[0]), np.asarray(sv[0]),
@@ -453,10 +466,12 @@ class PagedKVCache:
 
         A volume record is promoted to the host tier first (wire-crc
         verified in the pager), then the fused restore kernel re-verifies
-        the int8 payload against the spill-time checksums.  On a checksum
-        mismatch the allocated pool page goes back to the free list and
-        the host entries stay put (nothing is popped until the whole
-        page verified) — an IOError never leaks pool capacity."""
+        the int8 payload against the spill-time checksums, layer by
+        layer, as it restores them in place.  On a checksum mismatch the
+        allocated pool page goes back to the free list and the host
+        entries stay put (nothing is popped until the whole page
+        verified) — an IOError never leaks pool capacity, and only that
+        page's rows, which held no live data, were written."""
         with self.metrics.span("kv.page_in"):
             kind, payload = seq.table[logical]
             if kind == "vol":
@@ -472,48 +487,37 @@ class PagedKVCache:
             page = self._alloc_page()
             if page is None:
                 return False
-            pg, H, hd = self.cfg.page_size, self.cfg.n_kv_heads, self.cfg.head_dim
+            L, P = self.cfg.n_layers, self.cfg.n_pages
             if kind == "host":
-                ids = jnp.array([page], jnp.int32)
-                new_k, new_v = [], []
-                try:
-                    for li, (hk, hv) in enumerate(payload):
-                        qk, sk, ck = self.host.get(li, hk)
-                        qv, sv, cv = self.host.get(li, hv)
-                        pool_k = self.k_pool[li].reshape(self.cfg.n_pages, pg, -1)
-                        pool_v = self.v_pool[li].reshape(self.cfg.n_pages, pg, -1)
-                        # fused restore: dequantize+scatter AND checksum the int8
-                        # payload as received, in the same pass — verified against
-                        # the spill-time value before the page goes live
-                        pool_k, rck = scatter_dequantize_crc(
-                            pool_k, ids, jnp.asarray(qk)[None],
-                            jnp.asarray(sk)[None])
-                        pool_v, rcv = scatter_dequantize_crc(
-                            pool_v, ids, jnp.asarray(qv)[None],
-                            jnp.asarray(sv)[None])
-                        if int(rck[0]) != ck or int(rcv[0]) != cv:
-                            self.metrics.bump("transit_crc_errors")
-                            raise IOError(
-                                f"KV transit checksum mismatch: layer {li} page "
-                                f"{logical} of seq {seq.seq_id} tore in transit")
-                        new_k.append(pool_k.reshape(self.cfg.n_pages, pg, H, hd))
-                        new_v.append(pool_v.reshape(self.cfg.n_pages, pg, H, hd))
-                except IOError:
-                    self._free.append(page)                  # no capacity leak
-                    raise
+                for li, (hk, hv) in enumerate(payload):
+                    qk, sk, ck = self.host.get(li, hk)
+                    qv, sv, cv = self.host.get(li, hv)
+                    ids = jnp.asarray([li * P + page], jnp.int32)
+                    # fused restore: dequantize+scatter AND checksum the
+                    # int8 payload as received, in the same pass —
+                    # verified against the spill-time value before the
+                    # page goes live
+                    self.k_pool, rck = scatter_dequantize_crc(
+                        self.k_pool, ids, qk[None], sk[None])
+                    self.v_pool, rcv = scatter_dequantize_crc(
+                        self.v_pool, ids, qv[None], sv[None])
+                    if int(rck[0]) != ck or int(rcv[0]) != cv:
+                        self.metrics.bump("transit_crc_errors")
+                        self._free.append(page)          # no capacity leak
+                        raise IOError(
+                            f"KV transit checksum mismatch: layer {li} page "
+                            f"{logical} of seq {seq.seq_id} tore in transit")
                 for li, (hk, hv) in enumerate(payload):      # verified: commit
                     if self.read_tier is not None:
                         self.read_tier.invalidate(("page", li, hk, hv))
                     self.host.pop(li, hk)
                     self.host.pop(li, hv)
-                    self.k_pool[li] = new_k[li]
-                    self.v_pool[li] = new_v[li]
-            else:                                            # host-fresh (raw f32)
-                for li in range(self.cfg.n_layers):
-                    self.k_pool[li] = self.k_pool[li].at[page].set(
-                        jnp.asarray(payload["k"][li], self.cfg.dtype))
-                    self.v_pool[li] = self.v_pool[li].at[page].set(
-                        jnp.asarray(payload["v"][li], self.cfg.dtype))
+            else:                            # host-fresh (raw f32): one write
+                pg = self.cfg.page_size
+                self.k_pool, self.v_pool = _write_slots(
+                    self.metrics.bump, self.k_pool, self.v_pool,
+                    self._bases[range(L)], np.full((pg,), page, np.int32),
+                    np.arange(pg, dtype=np.int32), payload["k"], payload["v"])
             seq.table[logical] = ("hbm", page)
             self.metrics.bump("pages_in")
             return True
@@ -531,8 +535,6 @@ class PagedKVCache:
         with self._tlock:
             seq = self.seqs[sid]
             seq.active = False
-            if not self.cfg.eager_eviction:
-                return
             if self._evict_pool is not None:
                 for li, entry in enumerate(seq.table):
                     if entry[0] == "hbm":
@@ -652,12 +654,12 @@ class PagedKVCache:
         return jnp.asarray(table), jnp.asarray(lens)
 
     def _page_kv(self, layer: int, entry) -> tuple[np.ndarray, np.ndarray]:
-        """One logical page's (page_size, Hkv, hd) k/v from whichever tier
-        holds it (the transit read path: cache hit OR backend read)."""
-        pg, H, hd = self.cfg.page_size, self.cfg.n_kv_heads, self.cfg.head_dim
+        """One logical page's (page_size, Hkv*hd) k/v rows from whichever
+        tier holds it (the transit read path: cache hit OR backend read)."""
         if entry[0] == "hbm":
-            return (np.asarray(self.k_pool[layer][entry[1]], np.float32),
-                    np.asarray(self.v_pool[layer][entry[1]], np.float32))
+            row = layer * self.cfg.n_pages + entry[1]
+            return (np.asarray(self.k_pool[row], np.float32),
+                    np.asarray(self.v_pool[row], np.float32))
         if entry[0] == "host":
             hk, hv = entry[1][layer]
             if self.read_tier is not None:
@@ -666,8 +668,8 @@ class PagedKVCache:
                     return cached
             qk, sk, _ck = self.host.get(layer, hk)
             qv, sv, _cv = self.host.get(layer, hv)
-            k = (qk.astype(np.float32) * sk[:, None]).reshape(pg, H, hd)
-            v = (qv.astype(np.float32) * sv[:, None]).reshape(pg, H, hd)
+            k = qk.astype(np.float32) * sk[:, None]
+            v = qv.astype(np.float32) * sv[:, None]
             if self.read_tier is not None:
                 self.read_tier.insert(("page", layer, hk, hv), (k, v))
             return k, v
@@ -684,8 +686,8 @@ class PagedKVCache:
             layers = self._unpack_page(raw)
             out = None
             for li, (qk, sk, _ck, qv, sv, _cv) in enumerate(layers):
-                k = (qk.astype(np.float32) * sk[:, None]).reshape(pg, H, hd)
-                v = (qv.astype(np.float32) * sv[:, None]).reshape(pg, H, hd)
+                k = qk.astype(np.float32) * sk[:, None]
+                v = qv.astype(np.float32) * sv[:, None]
                 if self.read_tier is not None:
                     self.read_tier.insert(("vol-page", li, handle), (k, v))
                 if li == layer:
@@ -714,18 +716,20 @@ class PagedKVCache:
                            for sid in sids)
             if resident:
                 table, lens = self.table_for(sids)
+                base = self._bases[range(layer, layer + 1)]
                 if on_tpu() if use_kernel is None else use_kernel:
-                    return paged_attention(q, self.k_pool[layer],
-                                           self.v_pool[layer], table, lens)
-                return kref.paged_attention_ref(q, self.k_pool[layer],
-                                                self.v_pool[layer], table, lens)
+                    return paged_attention(q, self.k_pool, self.v_pool,
+                                           table, lens, base)
+                return kref.paged_attention_ref(q, self.k_pool, self.v_pool,
+                                                table, lens, base)
             self.metrics.bump("hybrid_attention")
-            pg, H, hd = self.cfg.page_size, self.cfg.n_kv_heads, self.cfg.head_dim
+            pg = self.cfg.page_size
+            F = self.cfg.n_kv_heads * self.cfg.head_dim
             B = len(sids)
             with self._tlock:
                 S = max(len(self.seqs[s].table) for s in sids) * pg
-                k = np.zeros((B, S, H, hd), np.float32)
-                v = np.zeros((B, S, H, hd), np.float32)
+                k = np.zeros((B, S, F), np.float32)
+                v = np.zeros((B, S, F), np.float32)
                 lens = np.zeros((B,), np.int32)
                 for bi, sid in enumerate(sids):
                     seq = self.seqs[sid]
@@ -734,12 +738,10 @@ class PagedKVCache:
                         pk, pv = self._page_kv(layer, entry)
                         k[bi, li * pg:(li + 1) * pg] = pk
                         v[bi, li * pg:(li + 1) * pg] = pv
-            # single-"page" ref attention over the materialized view
-            kpool = jnp.asarray(k).reshape(B * 1, S, H, hd)
-            vpool = jnp.asarray(v).reshape(B * 1, S, H, hd)
+            # single-"page" ref attention over the materialized rows
             table = jnp.arange(B, dtype=jnp.int32)[:, None]
-            return kref.paged_attention_ref(q, kpool, vpool, table,
-                                            jnp.asarray(lens))
+            return kref.paged_attention_ref(q, jnp.asarray(k), jnp.asarray(v),
+                                            table, jnp.asarray(lens))
 
     # ---------------------------------------------------------------- stats
     def occupancy(self) -> float:

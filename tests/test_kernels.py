@@ -8,9 +8,8 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import (flash_attention, gather_quantize,
-                           gather_quantize_crc, paged_attention,
-                           scatter_dequantize, scatter_dequantize_crc)
+from repro.kernels import (flash_attention, gather_quantize_crc,
+                           paged_attention, scatter_dequantize_crc)
 from repro.kernels import ref
 from repro.kernels.block_transit import (gather_quantize_crc_pallas,
                                          scatter_dequantize_crc_pallas)
@@ -71,8 +70,8 @@ def test_flash_attention_non_causal():
 def test_paged_attention_sweep(B, H, Hkv, hd, P, page, maxp, dtype):
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(ks[0], (B, H, hd), dtype)
-    kp = jax.random.normal(ks[1], (P, page, Hkv, hd), dtype)
-    vp = jax.random.normal(ks[2], (P, page, Hkv, hd), dtype)
+    kp = jax.random.normal(ks[1], (P, page, Hkv * hd), dtype)
+    vp = jax.random.normal(ks[2], (P, page, Hkv * hd), dtype)
     rng = np.random.default_rng(0)
     bt = jnp.asarray(rng.permutation(P)[:B * maxp].reshape(B, maxp),
                      jnp.int32)
@@ -82,6 +81,30 @@ def test_paged_attention_sweep(B, H, Hkv, hd, P, page, maxp, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_attention_row_base_selects_a_layer(layer):
+    """Three layers stacked as row blocks of P pages: with ``row_base``
+    layer*P the table's pages read that layer's rows, in the kernel and
+    in the ref, exactly as the layer's own pool read alone."""
+    B, H, Hkv, hd, P, page, maxp = 2, 4, 2, 64, 6, 8, 3
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = jax.random.normal(ks[0], (B, H, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (3 * P, page, Hkv * hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (3 * P, page, Hkv * hd), jnp.float32)
+    bt = jnp.asarray(np.random.default_rng(2).permutation(P)[:B * maxp]
+                     .reshape(B, maxp), jnp.int32)
+    sl = jnp.asarray([page * maxp, page + 3], jnp.int32)
+    base = jnp.asarray([layer * P], jnp.int32)
+    own = slice(layer * P, (layer + 1) * P)
+    alone = ref.paged_attention_ref(q, kp[own], vp[own], bt, sl)
+    np.testing.assert_array_equal(
+        np.asarray(ref.paged_attention_ref(q, kp, vp, bt, sl, base)),
+        np.asarray(alone))
+    np.testing.assert_allclose(
+        np.asarray(paged_attention(q, kp, vp, bt, sl, base)),
+        np.asarray(alone), atol=2e-5, rtol=2e-5)
 
 
 @settings(max_examples=15, deadline=None)
@@ -94,8 +117,8 @@ def test_paged_attention_respects_lengths(seq_lens):
     P = B * maxp
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(ks[0], (B, H, hd), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, page, Hkv, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, page, Hkv, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, page, Hkv * hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, page, Hkv * hd), jnp.float32)
     bt = jnp.arange(P, dtype=jnp.int32).reshape(B, maxp)
     sl = jnp.asarray(seq_lens, jnp.int32)
     out1 = paged_attention(q, kp, vp, bt, sl)
@@ -119,12 +142,12 @@ def test_transit_codec_roundtrip(P, page, F):
     pool = jax.random.normal(jax.random.PRNGKey(5), (P, page, F),
                              jnp.float32)
     ids = jnp.asarray(np.random.default_rng(1).permutation(P)[:3], jnp.int32)
-    q, sc = gather_quantize(pool, ids)
+    q, sc, _ = gather_quantize_crc(pool, ids)
     qr, sr = ref.gather_quantize_ref(pool, ids)
     assert np.array_equal(np.asarray(q), np.asarray(qr))
     np.testing.assert_allclose(np.asarray(sc), np.asarray(sr), rtol=1e-6)
     # roundtrip error bounded by one quantization step
-    restored = scatter_dequantize(jnp.zeros_like(pool), ids, q, sc)
+    restored, _ = scatter_dequantize_crc(jnp.zeros_like(pool), ids, q, sc)
     orig = np.asarray(pool)[np.asarray(ids)]
     got = np.asarray(restored)[np.asarray(ids)]
     step = np.abs(orig).max(axis=-1, keepdims=True) / 127.0
@@ -197,13 +220,13 @@ def test_scatter_preserves_other_pages():
     pool = jax.random.normal(jax.random.PRNGKey(6), (8, 16, 128),
                              jnp.float32)
     ids = jnp.asarray([2, 5], jnp.int32)
-    q, sc = gather_quantize(pool, ids)
-    out = scatter_dequantize(pool, ids, q, sc)
+    q, sc, _ = gather_quantize_crc(pool, ids)
+    before = np.asarray(pool)
+    out, _ = scatter_dequantize_crc(pool, ids, q, sc)     # donates pool
     for p in range(8):
         if p in (2, 5):
             continue
-        np.testing.assert_array_equal(np.asarray(out[p]),
-                                      np.asarray(pool[p]))
+        np.testing.assert_array_equal(np.asarray(out[p]), before[p])
 
 
 def test_flash_attention_grad_flows():

@@ -270,29 +270,74 @@ def test_drain_evictions_timeout_is_loud():
 
 
 # --------------------------------- satellite 4: crc + release accounting
-def test_page_in_crc_mismatch_returns_pool_page():
+@pytest.mark.parametrize("layer", [0, 1])
+def test_page_in_crc_mismatch_returns_pool_page(layer):
     """A corrupted host payload must surface as IOError + a counter bump
     WITHOUT leaking the pool page allocated for the restore, and without
-    popping any host handle (the sequence stays consistently cold)."""
+    popping any host handle (the sequence stays consistently cold).  The
+    restore writes in place, layer by layer, but only into that page's
+    rows: every other row of K and V, another sequence's included, keeps
+    its bits, and the page is back on the free list."""
     m = Metrics()
     c = PagedKVCache(_cfg(read_tier_pages=0), metrics=m)
     sid = c.new_sequence()
     _fill(c, sid, 4, np.random.default_rng(0))
+    other = c.new_sequence()
+    _fill(c, other, 8, np.random.default_rng(1))    # stays resident
     c.deactivate(sid)
     assert c.seqs[sid].table[0][0] == "host"
-    hk, _hv = c.seqs[sid].table[0][1][0]
-    q, s, crc = c.host.get(0, hk)
+    hk, _hv = c.seqs[sid].table[0][1][layer]
+    q, s, crc = c.host.get(layer, hk)
     q = q.copy()
     q[0, 0] ^= 0x5A                                # tear one byte
-    c.host.pages[(0, hk)] = (q, s, crc)
-    free_before = c.free_pages()
+    c.host.pages[(layer, hk)] = (q, s, crc)
+    free_before = sorted(c._free)
     host_before = len(c.host)
+    page = c._free[-1]                             # the restore's page
+    P = c.cfg.n_pages
+    others = [r for r in range(c.k_pool.shape[0]) if r % P != page]
+    k_before = np.array(c.k_pool)[others]
+    v_before = np.array(c.v_pool)[others]
     with pytest.raises(IOError, match="tore in transit"):
         c.activate(sid)
     assert m.count["transit_crc_errors"] == 1
-    assert c.free_pages() == free_before, "restore leaked a pool page"
+    assert c.free_pages() == len(free_before), "restore leaked a pool page"
+    assert sorted(c._free) == free_before and page in c._free
     assert len(c.host) == host_before, "partial page-in popped handles"
     assert c.seqs[sid].table[0][0] == "host"
+    assert [e[0] for e in c.seqs[other].table] == ["hbm", "hbm"]
+    assert np.array_equal(np.array(c.k_pool)[others], k_before)
+    assert np.array_equal(np.array(c.v_pool)[others], v_before)
+
+
+def test_host_fresh_page_in_is_one_write_of_every_layer():
+    """A bypassed (host-fresh, raw f32) page comes back to the pool in one
+    writer call for every layer: row li*P + page of each layer holds its
+    values cast to the pool dtype, and no other row moves."""
+    m = Metrics()
+    c = PagedKVCache(_cfg(n_pages=3, read_tier_pages=0, dtype=jnp.bfloat16),
+                     metrics=m)
+    o = c.new_sequence()
+    _fill(c, o, 4, np.random.default_rng(0))       # 1 page
+    a = c.new_sequence()
+    _fill(c, a, 12, np.random.default_rng(1))      # 2 pages, then bypass
+    kind, buf = c.seqs[a].table[2]
+    assert kind == "host-fresh" and m.count["kv.write_traces"] == 1
+    c.release(o)                                   # frees one page
+    page = c._free[-1]
+    before = {kv: np.array(getattr(c, kv + "_pool")) for kv in "kv"}
+    writes = m.count["kv_slot_writes"]
+    c.activate(a)
+    assert c.seqs[a].table[2] == ("hbm", page)
+    assert m.count["pages_in"] == 1 and m.count["kv.write_traces"] == 2
+    assert m.count["kv_slot_writes"] == writes
+    P = c.cfg.n_pages
+    for kv in "kv":
+        want = before[kv].copy()
+        for li in range(c.cfg.n_layers):
+            want[li * P + page] = buf[kv][li].astype(want.dtype)
+        got = np.array(getattr(c, kv + "_pool"))
+        assert got.tobytes() == want.tobytes()
 
 
 def test_release_accounts_mixed_hbm_host_fresh_pages():

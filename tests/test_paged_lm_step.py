@@ -108,40 +108,38 @@ def test_dense_math_traces_once_per_batch_shape(n_layers):
 
 
 # ------------------------------------------------------------ slot writes
-def _rows(pools, pages, rows):
-    """The writer's K or V rows as (n_pools, N, Hkv, hd) on the host."""
-    return np.asarray(rows).reshape((len(pools), len(pages))
-                                    + pools[0].shape[2:])
+def _rows(bases, pages, rows):
+    """The writer's K or V rows as (n_layers, N, Hkv*hd) on the host."""
+    return np.asarray(rows).reshape(len(bases), len(pages), -1)
 
 
-def _eager_writes(bump, k_pools, v_pools, pages, offs, k, v):
+def _eager_writes(bump, k_pool, v_pool, bases, pages, offs, k, v):
     """The write path the scatter replaced: one eager, un-donated
-    ``.at[page, off].set`` per slot and layer, host slots skipped."""
+    ``.at[row, off].set`` per slot and layer, at row ``base + page``;
+    a row past the pool (a host slot) is skipped."""
     out = []
-    for pools, rows in ((k_pools, k), (v_pools, v)):
-        rows = _rows(pools, pages, rows)
-        new = []
-        for li, pool in enumerate(pools):
+    for pool, rows in ((k_pool, k), (v_pool, v)):
+        rows = _rows(bases, pages, rows)
+        for li, base in enumerate(np.asarray(bases)):
             for n, (page, off) in enumerate(zip(np.asarray(pages),
                                                 np.asarray(offs))):
-                if page < pool.shape[0]:
-                    pool = pool.at[page, off].set(
+                if base + page < pool.shape[0]:
+                    pool = pool.at[base + page, off].set(
                         jnp.asarray(rows[li, n]).astype(pool.dtype))
-            new.append(pool)
-        out.append(tuple(new))
+        out.append(pool)
     return tuple(out)
 
 
 @pytest.fixture
 def writes(monkeypatch):
     """Every call of the slot writer, as (k rows, v rows) of shape
-    (n_pools, N, Hkv, hd), in call order."""
+    (n_layers, N, Hkv*hd), in call order."""
     calls = []
     orig = kvcache._write_slots
 
-    def spy(bump, k_pools, v_pools, pages, offs, k, v):
-        calls.append((_rows(k_pools, pages, k), _rows(v_pools, pages, v)))
-        return orig(bump, k_pools, v_pools, pages, offs, k, v)
+    def spy(bump, k_pool, v_pool, bases, pages, offs, k, v):
+        calls.append((_rows(bases, pages, k), _rows(bases, pages, v)))
+        return orig(bump, k_pool, v_pool, bases, pages, offs, k, v)
     monkeypatch.setattr(kvcache, "_write_slots", spy)
     return calls
 
@@ -153,8 +151,9 @@ def _same_bits(a, b) -> bool:
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_pools_equal_eager_per_slot_writes(name, writes):
     """Prefill, then decode steps across a page boundary: each pool holds,
-    bit for bit, what eager ``.at[page, off].set`` writes of the same rows
-    at the slots the tokens' positions map to."""
+    bit for bit, what eager ``.at[row, off].set`` writes of the same rows
+    at the slots the tokens' positions map to, row li*P + page for
+    layer li."""
     cfg = CONFIGS[name]()
     params = build_model(cfg).init(jax.random.PRNGKey(4))
     prompts = [[3, 4, 5, 6, 7], [8, 9, 10, 11, 12, 13, 14]]
@@ -164,8 +163,9 @@ def test_pools_equal_eager_per_slot_writes(name, writes):
     for step in range(6):                          # lengths 11 and 13
         tok = np.asarray(lm.decode_step(tok, sids, pos + step)
                          ).argmax(-1).astype(np.int32)
-    cache, pg, L = lm.cache, 8, cfg.n_layers
+    cache, pg, L, P = lm.cache, 8, cfg.n_layers, lm.cache.cfg.n_pages
     assert [len(cache.seqs[s].table) for s in sids] == [2, 2]
+    assert cache.k_pool.shape == (L * P, pg, cfg.n_kv_heads * cfg.hd)
 
     def slot(sid, t):
         kind, page = cache.seqs[sid].table[t // pg]
@@ -180,26 +180,25 @@ def test_pools_equal_eager_per_slot_writes(name, writes):
                                    for sid, prompt in zip(sids, prompts)])
               for step in range(6) for li in range(L)]
     assert len(writes) == len(order)
-    shape = cache.k_pool[0].shape
-    ref = {"k": [jnp.zeros(shape, cfg.dtype) for _ in range(L)],
-           "v": [jnp.zeros(shape, cfg.dtype) for _ in range(L)]}
+    ref = {kv: jnp.zeros(cache.k_pool.shape, cfg.dtype) for kv in "kv"}
     for (layers, toks), rows in zip(order, writes):
         for kv, r in zip("kv", rows):
             for i, li in enumerate(layers):
                 for n, (sid, t) in enumerate(toks):
                     page, off = slot(sid, t)
-                    ref[kv][li] = ref[kv][li].at[page, off].set(
+                    ref[kv] = ref[kv].at[li * P + page, off].set(
                         jnp.asarray(r[i, n]).astype(cfg.dtype))
-    for li in range(L):
-        assert _same_bits(cache.k_pool[li], ref["k"][li]), li
-        assert _same_bits(cache.v_pool[li], ref["v"][li]), li
+    assert _same_bits(cache.k_pool, ref["k"])
+    assert _same_bits(cache.v_pool, ref["v"])
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_bypassed_slot_is_written_on_the_host(name, writes):
     """A full pool sends one sequence's new page to the host tier: its row
     is written through numpy, the other sequence's in HBM, and the
-    counters say which."""
+    counters say which.  Of the whole K and V arrays only the HBM slot's
+    row in each layer changes: the host slot's sentinel page lands in no
+    layer's rows (not page 0 of the next layer)."""
     cfg = CONFIGS[name]()
     params = build_model(cfg).init(jax.random.PRNGKey(5))
     lm = _paged_lm(cfg, params, n_pages=2)
@@ -207,8 +206,8 @@ def test_bypassed_slot_is_written_on_the_host(name, writes):
     count, L = lm.cache.metrics.count, cfg.n_layers
     assert lm.cache.free_pages() == 0 and count["kv_slot_writes"] == 11
     n_calls = len(writes)
-    full = lm.cache.seqs[sids[0]].table[0][1]
-    before = [np.asarray(p[full]) for p in lm.cache.k_pool + lm.cache.v_pool]
+    before = {kv: np.array(getattr(lm.cache, kv + "_pool"))
+              for kv in "kv"}
     lm.decode_step(np.array([14, 15], np.int32), sids,
                    np.array([8, 3], np.int32))
     assert count["bypass_pages"] == 1 and count["hybrid_attention"] == L
@@ -216,17 +215,19 @@ def test_bypassed_slot_is_written_on_the_host(name, writes):
     assert count["kv_host_slot_writes"] == L
     kind, buf = lm.cache.seqs[sids[0]].table[1]
     assert kind == "host-fresh"
-    after = [np.asarray(p[full]) for p in lm.cache.k_pool + lm.cache.v_pool]
-    assert all(_same_bits(a, b) for a, b in zip(before, after))
     page = lm.cache.seqs[sids[1]].table[0][1]
+    want = {kv: a.copy() for kv, a in before.items()}
     for li, (k, v) in enumerate(writes[n_calls:]):
         np.testing.assert_array_equal(buf["k"][li, 0],
                                       k[0, 0].astype(np.float32))
         np.testing.assert_array_equal(buf["v"][li, 0],
                                       v[0, 0].astype(np.float32))
         assert not buf["k"][li, 1:].any()
-        assert _same_bits(lm.cache.k_pool[li][page, 3], k[0, 1])
-        assert _same_bits(lm.cache.v_pool[li][page, 3], v[0, 1])
+        want["k"][li * 2 + page, 3] = k[0, 1]
+        want["v"][li * 2 + page, 3] = v[0, 1]
+    assert len(writes) == n_calls + L
+    assert _same_bits(lm.cache.k_pool, want["k"])
+    assert _same_bits(lm.cache.v_pool, want["v"])
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
@@ -276,6 +277,5 @@ def test_decode_logits_equal_eager_per_slot_writes(name, monkeypatch):
         want = serve(eager)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
-    for li in range(cfg.n_layers):
-        assert _same_bits(scattered.cache.k_pool[li], eager.cache.k_pool[li])
-        assert _same_bits(scattered.cache.v_pool[li], eager.cache.v_pool[li])
+    assert _same_bits(scattered.cache.k_pool, eager.cache.k_pool)
+    assert _same_bits(scattered.cache.v_pool, eager.cache.v_pool)

@@ -8,6 +8,7 @@ one process at a time may load the TPU library, and pytest-xdist imports
 this file in every worker.  All such compiles live in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,9 +20,12 @@ from repro.kernels.block_transit import (gather_quantize_crc_pallas,
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 
-# qwen2.5-3b: 16 heads, 2 KV heads, head_dim 128 -> a KV page row of 256
-PAGES, PAGE, ROW = 256, 16, 256
+# qwen2.5-3b: 36 layers, 16 heads, 2 KV heads, head_dim 128 -> a KV page
+# row of 256; the cache stacks every layer's pages as row blocks
+L, PAGES, PAGE, ROW = 36, 256, 16, 256
 B, H, HKV, HD, MAX_PAGES = 4, 16, 2, 128, 64
+POOL = (L * PAGES, PAGE, ROW)
+POOL_BYTES = L * PAGES * PAGE * ROW * 2          # one bf16 pool
 
 
 @pytest.fixture(scope="module")
@@ -50,10 +54,32 @@ def _compile(fn, *args):
     lowered.compile()
 
 
+@pytest.fixture
+def ops(monkeypatch):
+    """The public kernel wrappers, traced as on a TPU (Mosaic kernels,
+    not interpret mode)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    return ops
+
+
+def _pool_users(hlo: str) -> list[str]:
+    """The instructions of a compiled module that take the ``k_pool`` or
+    ``v_pool`` parameter as an operand."""
+    params = re.findall(r"(%[kv]_pool[.\d]*) = \S+ parameter\(", hlo)
+    assert len(params) == 2, params
+    users = []
+    for line in hlo.splitlines():
+        _, eq, rhs = line.partition(" = ")
+        if eq and any(re.search(re.escape(p) + r"[,)]", rhs) for p in params):
+            users.append(rhs)
+    return users
+
+
 @pytest.mark.parametrize("n_ids", [1, 36])
 @pytest.mark.parametrize("direction", ["spill", "restore"])
 def test_codec_compiles_for_v5e(sds, direction, n_ids):
-    pool = sds((PAGES, PAGE, ROW), jnp.bfloat16)
+    pool = sds(POOL, jnp.bfloat16)
     ids = sds((n_ids,), jnp.int32)
     if direction == "spill":
         _compile(gather_quantize_crc_pallas, pool, ids)
@@ -64,9 +90,23 @@ def test_codec_compiles_for_v5e(sds, direction, n_ids):
 
 
 def test_paged_attention_compiles_for_v5e(sds):
-    kv = sds((PAGES, PAGE, HKV, HD), jnp.bfloat16)
+    kv = sds(POOL, jnp.bfloat16)
     _compile(paged_attention_pallas, sds((B, H, HD), jnp.bfloat16), kv, kv,
-             sds((B, MAX_PAGES), jnp.int32), sds((B,), jnp.int32))
+             sds((B, MAX_PAGES), jnp.int32), sds((B,), jnp.int32),
+             sds((1,), jnp.int32))
+
+
+def test_paged_attention_reads_row_pools_as_they_are(sds, ops):
+    """Decode attention as the cache calls it: every layer's row pool and
+    a traced layer offset.  The kernel takes both pools straight from
+    the parameters, with no relayout (reshape) or copy of either."""
+    kv = sds(POOL, jnp.bfloat16)
+    lowered = ops.paged_attention.lower(
+        sds((B, H, HD), jnp.bfloat16), kv, kv,
+        sds((B, MAX_PAGES), jnp.int32), sds((B,), jnp.int32),
+        sds((1,), jnp.int32))
+    users = _pool_users(lowered.compile().as_text())
+    assert len(users) == 1 and "tpu_custom_call" in users[0], users
 
 
 def test_flash_attention_compiles_for_v5e(sds):
@@ -75,17 +115,29 @@ def test_flash_attention_compiles_for_v5e(sds):
              kv, kv)
 
 
-@pytest.mark.parametrize("n_pools,n_slots", [(36, 1), (1, B)])
-def test_kv_slot_writer_compiles_in_place_for_v5e(sds, n_pools, n_slots):
+@pytest.mark.parametrize("n_layers,n_slots", [(L, 1), (1, B)])
+def test_kv_slot_writer_compiles_in_place_for_v5e(sds, n_layers, n_slots):
     """The KV slot writer at prefill's shape (every layer, one slot) and
-    decode's (one layer, B slots): the donated pools alias its outputs,
-    so the chip updates them in place rather than copying them."""
+    decode's (one layer, B slots): the donated K and V pools alias its
+    outputs whole, so the chip updates them in place rather than copying
+    them."""
     from repro.serve.kvcache import _write_slots
-    pools = tuple(sds((PAGES, PAGE, HKV, HD), jnp.bfloat16)
-                  for _ in range(n_pools))
-    rows = sds((n_pools, n_slots, HKV, HD), jnp.bfloat16)
+    pool = sds(POOL, jnp.bfloat16)
+    rows = sds((n_layers, n_slots, HKV, HD), jnp.bfloat16)
     idx = sds((n_slots,), jnp.int32)
-    compiled = _write_slots.lower(lambda name: None, pools, pools, idx, idx,
+    compiled = _write_slots.lower(lambda name: None, pool, pool,
+                                  sds((n_layers,), jnp.int32), idx, idx,
                                   rows, rows).compile()
-    pool_bytes = 2 * n_pools * PAGES * PAGE * HKV * HD * 2
-    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+    assert compiled.memory_analysis().alias_size_in_bytes == 2 * POOL_BYTES
+
+
+@pytest.mark.parametrize("n_ids", [1, L])
+def test_restore_codec_compiles_in_place_for_v5e(sds, ops, n_ids):
+    """The public restore codec donates its pool: the compiled call
+    aliases the whole pool to its output, so a page-in writes its rows
+    in place instead of copying every layer's."""
+    compiled = ops.scatter_dequantize_crc.lower(
+        sds(POOL, jnp.bfloat16), sds((n_ids,), jnp.int32),
+        sds((n_ids, PAGE, ROW), jnp.int8),
+        sds((n_ids, PAGE), jnp.float32)).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == POOL_BYTES
