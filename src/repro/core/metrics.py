@@ -210,7 +210,8 @@ SERVE_SPANS = (
     "lm.prefill",       # PagedLM.prefill: one prompt through the model into pages
     "kv.append",        # one layer's K/V writes for every sequence of a decode step
     "kv.attention",     # PagedKVCache.attention: block table upload, attention call
-    "kv.page_out",      # one HBM page to the host tier: codec, copies, host.put
+    "kv.page_out",      # one page-out batch to the host tier: 2 codec calls
+                        # per page, one transfer, host.put
     "kv.spill",         # host-tier overflow to the volume: pack, KVPager.spill, pops
     "kv.activate",      # PagedKVCache.activate: a session's pages back into HBM
     "kv.page_in",       # one page back into the pool, from the host tier or volume
@@ -234,6 +235,9 @@ SERVE_SPANS = (
 #                         is 1 slot across every layer, a decode layer B)
 #   kv_host_slot_writes — of those, slots on a host-fresh page, written
 #                         through numpy instead of the scatter
+#   kv_page_out_transfers — device-to-host transfers of page-out, one per
+#                         batch (one ``kv.page_out`` span); pages_out /
+#                         this is the pages each transfer carries
 
 
 #: EWMA smoothing for :meth:`Metrics.observe` — ~the last 10-ish

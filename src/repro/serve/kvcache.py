@@ -51,6 +51,14 @@ page-in of a bypassed page.  A slot on a host-resident page carries the
 page L*P, whose row li*P + L*P lies past the pool in every layer, so the
 scatter drops it.  The restore codec donates its pool as well.
 
+The transit path out is one codec call per pool per page, covering every
+layer: page p's rows are ``li*P + p`` for li in 0..L-1, one (L,) id
+array, so the codec compiles once whatever the number of pages.  A
+page-out batch (a sequence's HBM pages at ``deactivate``, or an eviction
+pool's batch) dispatches every page's two calls, brings all outputs to
+the host in one ``jax.device_get``, and only then files them in the host
+tier per layer and frees the pages.
+
 Concurrency contract: ``seq.table``, ``self._free``, the host tier and the
 active flags are guarded by ``_tlock`` — public entry points take it,
 ``_locked`` helpers assume it (the eviction-pool workers' ``_evict_slot*``
@@ -240,7 +248,7 @@ class PagedKVCache:
                 continue
             for li, entry in enumerate(seq.table):
                 if entry[0] == "hbm":
-                    self._page_out_locked(seq, li)
+                    self._page_out_locked([(seq, li)])
                     return True
         return False
 
@@ -344,27 +352,34 @@ class PagedKVCache:
                 "v": np.zeros(shape, np.float32)}
 
     # ----------------------------------------------------------- transit ops
-    def _page_out_locked(self, seq: Sequence, logical: int) -> None:
-        """Transit one HBM page to the host tier via the FUSED kernel:
-        gather + int8 pack + wire checksum in one VMEM pass (the old
-        path quantized, then walked the packed bytes again on the host
-        for the checksum)."""
+    def _page_out_locked(self, pages: list) -> None:
+        """Transit HBM pages, ``(seq, logical)`` each, to the host tier
+        via the FUSED kernel: gather + int8 pack + wire checksum in one
+        VMEM pass, one call per pool per page for every layer.  Every
+        call is dispatched before the one transfer to the host; a page
+        goes back to the free list only once its payload is there."""
+        if not pages:
+            return
         with self.metrics.span("kv.page_out"):
-            kind, page = seq.table[logical]
-            assert kind == "hbm"
-            handles = []
-            for li in range(self.cfg.n_layers):
-                ids = jnp.asarray([li * self.cfg.n_pages + page], jnp.int32)
-                qk, sk, ck = gather_quantize_crc(self.k_pool, ids)
-                qv, sv, cv = gather_quantize_crc(self.v_pool, ids)
-                hk = self.host.put(li, np.asarray(qk[0]), np.asarray(sk[0]),
-                                   int(ck[0]))
-                hv = self.host.put(li, np.asarray(qv[0]), np.asarray(sv[0]),
-                                   int(cv[0]))
-                handles.append((hk, hv))
-            seq.table[logical] = ("host", handles)
-            self._free.append(page)
-            self.metrics.bump("pages_out")
+            L, P = self.cfg.n_layers, self.cfg.n_pages
+            rows = np.arange(L, dtype=np.int32) * P
+            outs = []
+            for seq, logical in pages:
+                kind, page = seq.table[logical]
+                assert kind == "hbm"
+                ids = jnp.asarray(rows + page)
+                outs.append((gather_quantize_crc(self.k_pool, ids),
+                             gather_quantize_crc(self.v_pool, ids)))
+            outs = jax.device_get(outs)
+            self.metrics.bump("kv_page_out_transfers")
+            for (seq, logical), ((qk, sk, ck), (qv, sv, cv)) in zip(pages,
+                                                                     outs):
+                handles = [(self.host.put(li, qk[li], sk[li], int(ck[li])),
+                            self.host.put(li, qv[li], sv[li], int(cv[li])))
+                           for li in range(L)]
+                self._free.append(seq.table[logical][1])
+                seq.table[logical] = ("host", handles)
+            self.metrics.bump("pages_out", len(pages))
 
     # ------------------------------------------------------ volume spill tier
     def host_page_count(self) -> int:
@@ -528,7 +543,8 @@ class PagedKVCache:
         With an eviction pool attached, the page-out DMA (fused
         gather+quantize+checksum) is submitted to the volume's shared
         eviction cores instead of running on the decode thread.  The
-        sync fallback runs the whole page-out loop under ``_tlock`` —
+        sync fallback pages out all of the sequence's HBM pages as one
+        batch under ``_tlock`` —
         a concurrent deactivate of the same sequence sees "host"
         entries and skips, instead of double-freeing pool pages."""
         items = []
@@ -541,9 +557,9 @@ class PagedKVCache:
                         self._inflight_evictions += 1
                         items.append((seq, li))
             else:
-                for li, entry in enumerate(seq.table):
-                    if entry[0] == "hbm":
-                        self._page_out_locked(seq, li)
+                self._page_out_locked([(seq, li) for li, entry
+                                       in enumerate(seq.table)
+                                       if entry[0] == "hbm"])
                 self._maybe_spill_locked()
         for it in items:
             self._evict_pool.submit(self, it)
@@ -556,19 +572,25 @@ class PagedKVCache:
             if seq.active or seq.table[li][0] != "hbm":
                 self.metrics.bump("evict_skipped")
                 return
-            self._page_out_locked(seq, li)
+            self._page_out_locked([(seq, li)])
             self._maybe_spill_locked()
 
     def _evict_slots(self, items) -> None:
         """Batch drain hook: the pool hands several queued page-outs at
-        once; one lock acquisition covers the whole batch."""
+        once; one lock acquisition and one host transfer cover the whole
+        batch.  A page queued twice (two deactivates of one sequence) is
+        paged out once."""
         self.metrics.bump("evict_batches")
         with self._tlock:
+            batch, seen = [], set()
             for seq, li in items:
-                if seq.active or seq.table[li][0] != "hbm":
+                if (seq.active or seq.table[li][0] != "hbm"
+                        or (seq.seq_id, li) in seen):
                     self.metrics.bump("evict_skipped")
                     continue
-                self._page_out_locked(seq, li)
+                seen.add((seq.seq_id, li))
+                batch.append((seq, li))
+            self._page_out_locked(batch)
             self._maybe_spill_locked()
 
     def _complete_eviction(self) -> None:

@@ -362,6 +362,151 @@ def test_release_accounts_mixed_hbm_host_fresh_pages():
     assert c.seqs == {}
 
 
+# ------------------------------------------- page-out: one transfer a batch
+def _fill_layers(cache, sid, n_tokens, rng):
+    """Like ``_fill``, with different K/V in every layer."""
+    L = cache.cfg.n_layers
+    H, hd = cache.cfg.n_kv_heads, cache.cfg.head_dim
+    for _ in range(n_tokens):
+        k = rng.normal(size=(L, H, hd)).astype(np.float32)
+        v = rng.normal(size=(L, H, hd)).astype(np.float32)
+        cache.append_token(sid, list(k), list(v))
+
+
+@pytest.mark.parametrize("n_pages", [1, 3])
+def test_page_out_batch_is_bit_identical_to_the_reference(n_pages):
+    """A batched page-out files in the host tier exactly what a codec
+    call on one page of one layer gives: the same int8 rows, f32 scales
+    and Adler-32 word, so ``_pack_page`` writes the same spill record and
+    page-in restores the same rows; all of them equal the jnp reference,
+    compiled as the kernel is (run eagerly, its divide by 127 can round
+    the scale's last bit the other way, and with it a row's byte)."""
+    import jax
+
+    from repro.kernels import ref as kref
+    from repro.kernels.ops import gather_quantize_crc
+
+    gather_quantize_ref = jax.jit(kref.gather_quantize_ref)
+
+    m = Metrics()
+    c = PagedKVCache(_cfg(n_layers=3, read_tier_pages=0), metrics=m)
+    P, pg = c.cfg.n_pages, c.cfg.page_size
+    sid = c.new_sequence()
+    _fill_layers(c, sid, n_pages * pg, np.random.default_rng(7))
+    pools = {"k": c.k_pool, "v": c.v_pool}
+    pages = [e[1] for e in c.seqs[sid].table]
+    c.deactivate(sid)
+    assert m.count["kv_page_out_transfers"] == 1
+    want = {}
+    for logical, (kind, handles) in enumerate(c.seqs[sid].table):
+        assert kind == "host"
+        record = []
+        for li, hkv in enumerate(handles):
+            ids = jnp.asarray([li * P + pages[logical]], jnp.int32)
+            for kv, h in zip("kv", hkv):
+                q, s, crc = c.host.get(li, h)
+                qr, sr = (np.asarray(x) for x in
+                          gather_quantize_ref(pools[kv], ids))
+                q1, s1, c1 = (np.asarray(x)[0] for x in
+                              gather_quantize_crc(pools[kv], ids))
+                assert q.tobytes() == q1.tobytes() == qr[0].tobytes()
+                assert s.dtype == np.float32
+                assert s.tobytes() == s1.tobytes() == sr[0].tobytes()
+                assert crc == int(c1) == int(kref.transit_crc_ref(qr)[0])
+                assert crc == int(kref.transit_crc_ref(q[None])[0])
+                want[kv, li, logical] = q1, s1, int(c1)
+            (qk, sk, ck), (qv, sv, cv) = (want[kv, li, logical]
+                                          for kv in "kv")
+            record += [np.uint32(ck).tobytes(), np.uint32(cv).tobytes(),
+                       qk.tobytes(), sk.astype("<f4").tobytes(),
+                       qv.tobytes(), sv.astype("<f4").tobytes()]
+        assert c._pack_page(handles) == b"".join(record)
+    c.activate(sid)
+    assert m.count["transit_crc_errors"] == 0
+    for logical, (kind, page) in enumerate(c.seqs[sid].table):
+        assert kind == "hbm"
+        for kv in "kv":
+            got = np.asarray(getattr(c, kv + "_pool"))
+            for li in range(c.cfg.n_layers):
+                q, s, _ = want[kv, li, logical]
+                deq = (q.astype(np.float32) * s[:, None]).astype(got.dtype)
+                assert got[li * P + page].tobytes() == deq.tobytes()
+
+
+class _QueueOnly:
+    """An eviction pool that only records what it is handed."""
+
+    def __init__(self):
+        self.items = []
+
+    def register(self, cache):
+        pass
+
+    def submit(self, cache, item):
+        self.items.append(item)
+
+
+@pytest.mark.parametrize("n_pages", [1, 3])
+def test_page_out_is_two_codec_calls_a_page_and_one_transfer(
+        n_pages, monkeypatch):
+    """A deactivate of k HBM pages makes 2k codec calls, each on the (L,)
+    ids of one page in every layer, and one host transfer; an eviction
+    pool's batch is one transfer too, and a page it holds twice goes out
+    once."""
+    from repro.serve import kvcache
+
+    shapes = []
+    transfers = []
+    codec, device_get = kvcache.gather_quantize_crc, kvcache.jax.device_get
+
+    def counting_codec(pool, ids):
+        shapes.append((ids.shape, ids.dtype))
+        return codec(pool, ids)
+
+    def counting_get(x):
+        transfers.append(len(x))
+        return device_get(x)
+
+    monkeypatch.setattr(kvcache, "gather_quantize_crc", counting_codec)
+    monkeypatch.setattr(kvcache.jax, "device_get", counting_get)
+    L, pg = 3, 4
+    rng = np.random.default_rng(8)
+
+    m = Metrics()
+    c = PagedKVCache(_cfg(n_layers=L, read_tier_pages=0), metrics=m)
+    sid = c.new_sequence()
+    _fill_layers(c, sid, n_pages * pg, rng)
+    c.deactivate(sid)
+    assert len(shapes) == 2 * n_pages
+    assert set(shapes) == {((L,), np.dtype(np.int32))}
+    assert transfers == [n_pages]
+    assert m.count["kv_page_out_transfers"] == 1
+    assert m.count["pages_out"] == n_pages == m.count["kv.page_out"] * n_pages
+
+    pool = _QueueOnly()
+    m2 = Metrics()
+    c2 = PagedKVCache(_cfg(n_layers=L, read_tier_pages=0), metrics=m2,
+                      evict_pool=pool)
+    sids = [c2.new_sequence() for _ in range(2)]
+    for s in sids:
+        _fill_layers(c2, s, n_pages * pg, rng)
+        c2.deactivate(s)
+    c2.deactivate(sids[0])                         # queued twice
+    assert len(pool.items) == 3 * n_pages
+    shapes.clear()
+    transfers.clear()
+    c2._evict_slots(pool.items)
+    for _ in pool.items:
+        c2._complete_eviction()
+    assert len(shapes) == 2 * 2 * n_pages
+    assert transfers == [2 * n_pages]
+    assert m2.count["kv_page_out_transfers"] == 1
+    assert m2.count["pages_out"] == 2 * n_pages
+    assert m2.count["evict_skipped"] == n_pages
+    assert len(c2._free) == len(set(c2._free)) == c2.cfg.n_pages
+    assert all(e[0] == "host" for s in sids for e in c2.seqs[s].table)
+
+
 # ------------------------------------------------------- engine + metrics
 def test_kv_paging_path_metrics_shape():
     m = Metrics()
@@ -460,8 +605,9 @@ def test_page_record_bytes_sizes_the_spill_tier(n_layers, page_size,
 def test_engine_spans_count_where_the_work_happens():
     """Every serving span is entered on the engine's shared Metrics (the
     volume ops on the volume's own): one ``kv.append`` and one
-    ``kv.attention`` per layer per decode step, one page span per page
-    moved, one ``pager.fetch`` per volume restore."""
+    ``kv.attention`` per layer per decode step, one ``kv.page_out`` per
+    host transfer of a page-out batch, one ``kv.page_in`` per page moved
+    in, one ``pager.fetch`` per volume restore."""
     import jax
 
     from repro.configs import get_config
@@ -486,7 +632,8 @@ def test_engine_spans_count_where_the_work_happens():
     assert c["lm.decode"] >= 5 and c["lm.prefill"] == 2
     assert c["kv.append"] == c["kv.attention"] == cfg.n_layers * c["lm.decode"]
     assert c["serve.suspend"] == 1 and c["serve.step"] >= c["lm.decode"]
-    assert c["kv.page_out"] == c["pages_out"] > 0
+    assert c["kv.page_out"] == c["kv_page_out_transfers"] > 0
+    assert c["pages_out"] >= c["kv.page_out"]
     assert c["kv.page_in"] == c["pages_in"] > 0
     assert c["pager.fetch"] == c["kv_restores"] > 0
     assert c["kv.activate"] == c["resumes"] and c["kv.spill"] > 0
